@@ -570,7 +570,7 @@ def bench_session_streaming(
     wave_keys = [JOB_ORDER[i % len(JOB_ORDER)] for i in range(per)]
     # Build every wave's job objects up front (through the shared simulator
     # memo): the timed region below measures the SESSION — probe/profile,
-    # on-device split, lockstep search, warm seeding — not harness setup.
+    # vectorized split, lockstep search, warm seeding — not harness setup.
     wave_jobs = [
         cluster_fleet(wave_keys, sims={k: get_sim(k) for k in wave_keys})
         for _ in range(waves)
@@ -1460,5 +1460,8 @@ if __name__ == "__main__":
                     help="shard counts for the job-axis sharding sweep on "
                          "the service fleet (default: 2)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(args.jobs, check=not args.no_check, smoke=args.smoke,
         session_only=args.session, shards=tuple(args.shards))

@@ -6,11 +6,6 @@ measure loop's instrument.
         --cell train_4k --micro 2 --attention chunked --chunk 1024
 """
 
-import os
-
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import dataclasses
 import json
@@ -106,4 +101,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.hostdevices import force_host_device_count
+
+    force_host_device_count(512)  # the production mesh's placeholder devices
     main()

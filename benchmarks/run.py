@@ -51,6 +51,9 @@ def main() -> None:
 
         force_host_device_count(max([2] + list(args.shards or [])))
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig1_memory_cliff,
         fig4_convergence,

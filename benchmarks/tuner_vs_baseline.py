@@ -30,11 +30,13 @@ TRIAL_CHIPS = 256
 
 
 def run(arch: str = "granite-8b", cell: str = "train_4k", seeds: int = 25) -> dict:
-    """Driver entry: the tuner needs 512 placeholder devices, but the
-    benchmark driver's process may already hold a 1-device jax — always run
-    the real work in a subprocess with its own XLA_FLAGS."""
+    """Driver entry: the tuner needs 512 placeholder CPU devices, but the
+    benchmark driver's process has already initialized jax (and may hold
+    an accelerator) — always run the real work in a CPU-only subprocess
+    with its own XLA_FLAGS."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("PYTHONPATH", "src")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.tuner_vs_baseline",
@@ -50,7 +52,6 @@ def run(arch: str = "granite-8b", cell: str = "train_4k", seeds: int = 25) -> di
 
 def _run_inprocess(arch: str = "granite-8b", cell: str = "train_4k",
                    seeds: int = 25) -> dict:
-    # Import inside: sets XLA device-count flag for the compile trials.
     from repro.launch.autotune import (
         HBM_PER_CHIP,
         TpuTunerEnv,
@@ -138,9 +139,9 @@ def _run_inprocess(arch: str = "granite-8b", cell: str = "train_4k",
 if __name__ == "__main__":
     import argparse
 
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=512"
-    )
+    from repro.hostdevices import force_host_device_count
+
+    force_host_device_count(512)  # the production mesh's placeholder devices
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--cell", default="train_4k")

@@ -3,7 +3,7 @@
 A `TuningSession` is a long-lived tuning service.  Jobs are submitted in
 waves (here: the paper's recurring Spark/Hadoop workloads re-arriving, the
 Blink scenario); each submission is probe-classified against the session's
-`ProfileCache`, its §III-D split is computed on device, and the search
+`ProfileCache`, its §III-D split is computed vectorized, and the search
 joins a lockstep chunk at the next `step()`.  Once a memory-signature
 class has completed trials, later arrivals in the same class are
 WARM-STARTED: their packed observation/feature buffers are seeded from the

@@ -31,7 +31,7 @@ from repro.core.profiler import ProfileResult, profile_job, schedule_sample_size
 from repro.core.search_space import (
     Configuration,
     SearchSpace,
-    split_masks_device,
+    split_priority_mask,
     split_search_space,
 )
 from repro.core.tuner import RuyaReport, run_cherrypick, run_ruya
@@ -60,6 +60,6 @@ __all__ = [
     "run_cherrypick",
     "run_ruya",
     "schedule_sample_sizes",
-    "split_masks_device",
+    "split_priority_mask",
     "split_search_space",
 ]

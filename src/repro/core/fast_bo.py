@@ -30,9 +30,10 @@ Session-era paths ride the same step with zero new device code (PR 4):
     warm seeding     O(w·d) host prefill of the packed (B,)/(B,d) buffers
                      before the first step; a seeded search starts at t = w,
                      so it runs ≤ B − w fresh steps at unchanged extents
-    on-device split  O(n log n) §III-D mask build once per admission
-                     (search_space.split_masks_device), float64, bit-equal
-                     to the host rule — no O(n) Python narrowing loop
+    vectorized split O(n log n) §III-D mask build once per admission
+                     (search_space.split_priority_mask), float64 on the
+                     host, bit-equal to the host rule — no O(n) Python
+                     narrowing loop
     sharded step     one `shard_map` dispatch advances S chunks, one per
                      device (repro.fleet.sharding): per-device compute is
                      the unchanged extent-r chunk program, communication
@@ -286,12 +287,16 @@ def _packed_head(
     d2_bb: jax.Array,  # (B, B) raw squared distances, training block
     py: jax.Array,  # (B,) f32 packed observed costs, trial order
     t: jax.Array,  # () i32 observations made (valid packed slots)
+    lengthscales: Tuple[float, ...] = _LENGTHSCALES,
+    noises: Tuple[float, ...] = _NOISES,
 ) -> Tuple[jax.Array, ...]:
     """The training-side math every packed layout shares: target
     standardization, the 18-point (lengthscale, noise) grid, masked
     Cholesky factorizations, and marginal-likelihood selection.  Everything
     here is extent-B — the space extent n never appears — so the fused
-    layout runs it verbatim and streams only the tail.
+    layout runs it verbatim and streams only the tail.  A narrower grid
+    (``lengthscales`` × ``noises``) serves cross-backend checks that pin
+    the selection.
 
     Returns ``(pm, best, ls_sel, chol, alpha, y_mean, y_std)``: the
     selected posterior factors the EI tail consumes.
@@ -310,8 +315,8 @@ def _packed_head(
     # The kernel depends on the lengthscale only, and a scalar lengthscale
     # only rescales d²: 6 elementwise rescales of one (B,B) block serve all
     # 18 (lengthscale, noise) grid points.
-    ls = jnp.asarray(_LENGTHSCALES, jnp.float32)
-    nz = jnp.asarray(_NOISES, jnp.float32)
+    ls = jnp.asarray(lengthscales, jnp.float32)
+    nz = jnp.asarray(noises, jnp.float32)
     ks = jax.vmap(lambda l: matern52_from_sqdist(d2_bb, l))(ls)  # (6, B, B)
 
     mm = pm[:, None] * pm[None, :]
@@ -327,7 +332,9 @@ def _packed_head(
         chol = jnp.linalg.cholesky(k_eff)
         alpha = jax.scipy.linalg.cho_solve((chol, True), y_train)
         lml = (
-            -0.5 * y_train @ alpha
+            jnp.matmul(
+                -0.5 * y_train, alpha, precision=jax.lax.Precision.HIGHEST
+            )
             - jnp.sum(jnp.log(jnp.diagonal(chol)) * pm)
             - 0.5 * jnp.sum(pm) * jnp.log(2.0 * jnp.pi)
         )
@@ -368,7 +375,7 @@ def _packed_core(
     # Posterior + EI over all n points for the selected hyperparameters
     # only: one (B,n) rescale of the cross block, masked training rows.
     ei = ei_from_sqdist(
-        d2_bn, pm, alpha, chol, ls_sel, y_mean, y_std, best,
+        d2_bn, pm[:, None], alpha, chol, ls_sel, y_mean, y_std, best,
         cand_mask & ~obs_mask, xi,
     )
     pick = jnp.argmax(ei)

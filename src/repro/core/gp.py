@@ -79,14 +79,16 @@ def pairwise_sqdist(x1: jax.Array, x2: jax.Array = None) -> jax.Array:
     Lengthscale-free: a scalar lengthscale only rescales d² (d²/ls²), so one
     precomputed tensor serves every point of a lengthscale grid — and, in
     `fast_bo`, every step of a whole search.  Clamped at zero (the quadratic
-    expansion can go slightly negative in float32).
+    expansion can go slightly negative in float32).  The cross term runs at
+    full float32 precision: a TPU's default precision would round it to
+    bfloat16 (XLA:CPU ignores the setting for float32).
     """
     if x2 is None:
         x2 = x1
     d2 = (
         jnp.sum(x1**2, -1)[:, None]
         + jnp.sum(x2**2, -1)[None, :]
-        - 2.0 * x1 @ x2.T
+        - jnp.matmul(2.0 * x1, x2.T, precision=jax.lax.Precision.HIGHEST)
     )
     return jnp.maximum(d2, 0.0)
 

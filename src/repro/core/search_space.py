@@ -13,15 +13,17 @@ metadata (node count, prices, mesh/remat details for the TPU tuner, ...).
   FLAT    → the 10–20 % of configs with the lowest total memory.
   UNCLEAR → no split (priority group = whole space → plain CherryPick).
 
-``split_masks_device`` is the same rule computed ON DEVICE over the space's
-static per-config arrays (total memories, node counts), returning the
-(n,) priority mask directly — the narrowing then scales with the catalog
-(one vectorized comparison + a stable sort instead of a Python loop over
-10⁴–10⁵ configs).  It runs in float64 (`jax.experimental.enable_x64`) so
-every comparison and the stable sort are bit-equal to the host rule —
+``split_priority_mask`` is the same rule vectorized over the space's static
+per-config arrays (total memories, node counts), returning the (n,) priority
+mask directly — the narrowing then scales with the catalog (one vectorized
+float64 comparison + a stable sort instead of a Python loop over 10⁴–10⁵
+configs).  It runs in NumPy on the host, where float64 is IEEE: every
+comparison and the stable sort are bit-equal to the host rule —
 `tests/test_search_space.py` pins mask == list equality, which is what lets
-`repro.fleet.session.TuningSession` use the device split while staying
-trace-identical to the host-split drivers.
+`repro.fleet.session.TuningSession` use the vectorized split while staying
+trace-identical to the host-split drivers.  (A TPU has no native float64:
+XLA emulates it with float32 pairs, which is not IEEE-exact, so the split
+does not run there.)
 """
 
 from __future__ import annotations
@@ -36,7 +38,7 @@ from repro.core.memory_model import MemoryCategory, MemoryModel
 __all__ = [
     "Configuration",
     "SearchSpace",
-    "split_masks_device",
+    "split_priority_mask",
     "split_search_space",
 ]
 
@@ -142,46 +144,7 @@ def split_search_space(
     return prio, rest
 
 
-def _jit64(fun):
-    """jit a float64 split kernel lazily (jax import deferred to first use)."""
-    cache = {}
-
-    def wrapper(*args, k: int):
-        import jax
-
-        if "fn" not in cache:
-            cache["fn"] = jax.jit(fun, static_argnames=("k",))
-        return cache["fn"](*args, k=k)
-
-    return wrapper
-
-
-@_jit64
-def _flat_prio_mask(mems, *, k: int):
-    """FLAT rule: True at the k lowest-memory configs (stable ties)."""
-    import jax.numpy as jnp
-
-    order = jnp.argsort(mems, stable=True)
-    return jnp.zeros(mems.shape[0], bool).at[order[:k]].set(True)
-
-
-@_jit64
-def _linear_prio_mask(mems, nodes, req_base, leeway, overhead, *, k: int):
-    """LINEAR rule: memory ≥ requirement, else the very-high/very-low extremes."""
-    import jax.numpy as jnp
-
-    requirement = req_base * (1.0 + leeway) + overhead * nodes
-    qualify = mems >= requirement
-    order = jnp.argsort(mems, stable=True)
-    extremes = (
-        jnp.zeros(mems.shape[0], bool)
-        .at[order[:k]].set(True)
-        .at[order[-k:]].set(True)
-    )
-    return jnp.where(jnp.any(qualify), qualify, extremes)
-
-
-def split_masks_device(
+def split_priority_mask(
     space: SearchSpace,
     model: MemoryModel,
     input_size: float,
@@ -190,39 +153,38 @@ def split_masks_device(
     leeway: float = 0.10,
     flat_fraction: float = 1.0 / 7.0,
     extreme_fraction: float = 0.15,
-):
-    """§III-D priority split computed ON DEVICE; returns the (n,) bool mask.
+) -> np.ndarray:
+    """§III-D priority split, vectorized; returns the (n,) bool mask.
 
     Bit-equal to `split_search_space` by construction: the per-config
-    requirement math runs elementwise in float64 (under
-    `jax.experimental.enable_x64`, so device IEEE ops match the host's), the
-    FLAT / extremes selections use a stable argsort (same permutation as
-    `np.argsort(kind="stable")`), and the group sizes ``k`` are rounded on
-    the host with the same expressions.  The remaining mask is always the
-    complement (`~prio`) — including the all-qualify LINEAR case, where the
-    complement of an all-True mask is the host rule's empty remainder.
+    requirement math is the same float64 IEEE arithmetic elementwise, the
+    FLAT / extremes selections use the same stable argsort, and the group
+    sizes ``k`` are rounded with the same expressions.  The remaining mask
+    is always the complement (`~prio`) — including the all-qualify LINEAR
+    case, where the complement of an all-True mask is the host rule's
+    empty remainder.
 
-    The host-side cost is O(1): the static per-config arrays come from the
-    `SearchSpace` cache, so narrowing a 10⁴–10⁵-point catalog is one device
-    comparison + sort instead of a Python loop over configs.
+    The static per-config arrays come from the `SearchSpace` cache, so
+    narrowing a 10⁴–10⁵-point catalog is one vectorized comparison + sort
+    instead of a Python loop over configs.
     """
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
     n = len(space)
     if model.category is MemoryCategory.UNCLEAR:
-        return jnp.ones(n, bool)
-    with enable_x64():
-        mems = jnp.asarray(space.memories())
-        if model.category is MemoryCategory.FLAT:
-            k = max(1, int(round(flat_fraction * n)))
-            return _flat_prio_mask(mems, k=min(k, n))
-        k = max(1, int(round(extreme_fraction * n)))
-        return _linear_prio_mask(
-            mems,
-            jnp.asarray(space.num_nodes()),
-            jnp.asarray(np.float64(model.estimate(input_size))),
-            jnp.asarray(np.float64(leeway)),
-            jnp.asarray(np.float64(per_node_overhead)),
-            k=min(k, n),
-        )
+        return np.ones(n, bool)
+    mems = space.memories()
+    order = np.argsort(mems, kind="stable")
+    prio = np.zeros(n, bool)
+    if model.category is MemoryCategory.FLAT:
+        prio[order[: max(1, int(round(flat_fraction * n)))]] = True
+        return prio
+    requirement = (
+        model.estimate(input_size) * (1.0 + leeway)
+        + per_node_overhead * space.num_nodes()
+    )
+    qualify = mems >= requirement
+    if qualify.any():
+        return qualify
+    k = max(1, int(round(extreme_fraction * n)))
+    prio[order[:k]] = True
+    prio[order[-k:]] = True
+    return prio

@@ -8,8 +8,8 @@ negligible per-job overhead.  This package provides:
     `step()` advances every live search one batched BO iteration (newly
     submitted jobs are admitted into lockstep chunks between steps),
     `drain()`/`results()` return first-class `TrialRecord`/`SearchOutcome`
-    structures.  The session owns the `ProfileCache`, computes the §III-D
-    split on device, and warm-starts searches from completed trials in the
+    structures.  The session owns the `ProfileCache`, computes the
+    vectorized §III-D split, and warm-starts searches from completed trials in the
     same memory-signature class.
   * `batched_engine.batched_search` — one-shot shim over a session: J
     independent Ruya/CherryPick searches in device-resident lockstep (one

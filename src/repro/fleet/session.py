@@ -50,10 +50,10 @@ function of (class history, seed): the history is ordered by completion,
 deduplicated by config index, and truncated capacity-aware, and no RNG is
 consumed when seeding happens.
 
-Memory-aware narrowing runs ON DEVICE: the §III-D priority split comes from
-`repro.core.search_space.split_masks_device` (float64 on device, bit-equal
-to the host rule), so admission cost scales with the catalog — no Python
-loop over 10⁴–10⁵ configurations.
+Memory-aware narrowing is vectorized: the §III-D priority split comes from
+`repro.core.search_space.split_priority_mask` (float64 NumPy on the host,
+bit-equal to the host rule), so admission cost scales with the catalog — no
+Python loop over 10⁴–10⁵ configurations.
 
 `run_ruya` / `run_cherrypick` / `tune_fleet` / `batched_search` remain as
 thin deprecation shims over this engine.
@@ -87,7 +87,7 @@ from repro.core.profiler import (
     ProfilingRunError,
     profile_job,
 )
-from repro.core.search_space import split_masks_device
+from repro.core.search_space import split_priority_mask
 from repro.core.tuner import RuyaReport
 # The jitted lockstep update and the chunking constants are shared verbatim
 # with the pre-session engine (see `repro.fleet.batched_engine` for why 8:
@@ -771,7 +771,7 @@ class TuningSession:
         Passing ``priority``/``remaining`` explicitly skips profiling and
         uses the given split verbatim (the `batched_search` shim's path);
         otherwise "ruya" resolves a profile (``job.profile_result``, else the
-        session `ProfileCache`) and computes the §III-D split on device,
+        session `ProfileCache`) and computes the vectorized §III-D split,
         while "cherrypick" searches the whole space.  ``warm_start``
         overrides the session default for this job; seeding only happens for
         profiled jobs (the signature is the class key) and consumes no RNG.
@@ -890,9 +890,9 @@ class TuningSession:
                 if self.cache is not None
                 else MemorySignature.of(profile.model)
             )
-            # §III-D narrowing, computed on device from the static
-            # per-config arrays; remaining is always the complement.
-            prio_dev = split_masks_device(
+            # §III-D narrowing, vectorized over the static per-config
+            # arrays; remaining is always the complement.
+            prio_mask = split_priority_mask(
                 space,
                 profile.model,
                 job.full_input_size,
@@ -900,7 +900,6 @@ class TuningSession:
                 leeway=job.leeway,
                 flat_fraction=job.flat_fraction,
             )
-            prio_mask = np.asarray(prio_dev)
             rem_mask = ~prio_mask
             prio_idx = np.flatnonzero(prio_mask)
             rem_idx = np.flatnonzero(rem_mask)

@@ -79,7 +79,7 @@ def _ei_argmax_scan(
         et = jax.lax.dynamic_slice(enc, (off, 0), (tile, d))
         mt = jax.lax.dynamic_slice(mask, (off,), (tile,))
         ei = ei_from_sqdist(
-            pairwise_sqdist(feats, et), pm, alpha, chol,
+            pairwise_sqdist(feats, et), pm[:, None], alpha, chol,
             ls, y_mean, y_std, best, mt, xi,
         )
         tile_max = jnp.max(ei)
@@ -141,7 +141,7 @@ def ei_argmax(
             tile=t, xi=float(xi),
             interpret=bool(interpret) if interpret is not None else False,
         )
-        return idx[0], val[0]
+        return idx[0, 0], val[0, 0]
     return _ei_argmax_scan(
         enc, mask, feats, pm, alpha, chol, ls, y_mean, y_std, best,
         float(xi), t,

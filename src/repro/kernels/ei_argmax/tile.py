@@ -21,57 +21,80 @@ test_ei_argmax_kernel.py` and the golden fixtures):
     Pallas kernel body may not capture traced constants, and
     float32(math.sqrt(2.0)) rounds to the identical bits as
     float32(jnp.sqrt(2.0)) — the XLA lanes lose nothing.
-  * The solve is injectable: the CPU/interpret lanes use LAPACK's
-    `solve_triangular` (column-slice invariant — solving for a subset of
-    right-hand-side columns reproduces the full solve's bits), while the
-    compiled-TPU kernel substitutes a Mosaic-lowerable forward
-    substitution (`kernel._forward_substitution`); per-backend bits may
-    differ, exactly like the rest of the engine's per-backend float32
-    contract.
+  * Three ops are injectable (`TailOps`): the posterior mean contraction,
+    the triangular-solve column norm, and the normal CDF.  Every XLA lane
+    (reference, scan, interpret) uses `XLA_TAIL_OPS` — LAPACK's
+    `solve_triangular` is column-slice invariant, so solving for a subset
+    of right-hand-side columns reproduces the full solve's bits.  The
+    compiled-TPU kernel substitutes Mosaic-lowerable ops
+    (`kernel.MOSAIC_TAIL_OPS`); per-backend bits may differ, exactly like
+    the rest of the engine's per-backend float32 contract.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.gp import matern52_from_sqdist
 
-__all__ = ["ei_from_sqdist"]
+__all__ = ["TailOps", "XLA_TAIL_OPS", "ei_from_sqdist"]
 
 _SQRT2 = math.sqrt(2.0)
 _SQRT2PI = math.sqrt(2.0 * math.pi)
 
 
-def _solve_lower(chol: jax.Array, rhs: jax.Array) -> jax.Array:
-    return jax.scipy.linalg.solve_triangular(chol, rhs, lower=True)
+class TailOps(NamedTuple):
+    """The backend-specific ops of the EI tail."""
+
+    mean: Callable  # (k_star (B,m), alpha) → posterior mean over m columns
+    sqnorm: Callable  # (chol, k_star) → column sums of (L⁻¹ k_star)²
+    cdf: Callable  # z → standard normal CDF
+
+
+def _xla_mean(k_star: jax.Array, alpha: jax.Array) -> jax.Array:
+    return jnp.matmul(k_star.T, alpha, precision=jax.lax.Precision.HIGHEST)
+
+
+def _xla_sqnorm(chol: jax.Array, k_star: jax.Array) -> jax.Array:
+    v = jax.scipy.linalg.solve_triangular(chol, k_star, lower=True)
+    return jnp.sum(v * v, axis=0)
+
+
+def _xla_cdf(z: jax.Array) -> jax.Array:
+    return 0.5 * (1.0 + jax.scipy.special.erf(z / _SQRT2))
+
+
+XLA_TAIL_OPS = TailOps(mean=_xla_mean, sqnorm=_xla_sqnorm, cdf=_xla_cdf)
 
 
 def ei_from_sqdist(
     d2: jax.Array,  # (B, m) raw squared distances, training rows × candidates
-    pm: jax.Array,  # (B,) f32 packed-slot validity (1.0 for slots < t)
-    alpha: jax.Array,  # (B,) K⁻¹ y_train for the selected hyperparameters
+    pm: jax.Array,  # (B, 1) f32 packed-slot validity (1.0 for slots < t)
+    alpha: jax.Array,  # K⁻¹ y_train for the selected hyperparameters
     chol: jax.Array,  # (B, B) Cholesky factor of the masked training kernel
     ls: jax.Array,  # () selected lengthscale
     y_mean: jax.Array,  # () training-target mean
     y_std: jax.Array,  # () training-target std (clamped)
     best: jax.Array,  # () best observed cost (un-standardized)
-    mask: jax.Array,  # (m,) bool — candidate mask; False → EI = -inf
+    mask: jax.Array,  # candidate mask over the m columns; False → EI = -inf
     xi: float = 0.0,
     *,
-    solve=_solve_lower,
+    ops: TailOps = XLA_TAIL_OPS,
 ) -> jax.Array:
-    """Masked EI over the m candidate columns of ``d2``; (m,) float32.
+    """Masked EI over the m candidate columns of ``d2``, float32.
 
     ``m`` may be the full space extent n (the reference lane) or one tile
     (the fused lanes) — the bits per column are identical either way.
+    With `XLA_TAIL_OPS`, ``alpha`` is (B,) and the result (m,); the
+    Mosaic ops take a (B,1) ``alpha`` and keep a leading unit axis.
     """
-    k_star = matern52_from_sqdist(d2, ls) * pm[:, None]
-    mean_n = k_star.T @ alpha
-    v = solve(chol, k_star)
-    var_n = jnp.maximum(1.0 - jnp.sum(v * v, axis=0), 1e-12)
+    k_star = matern52_from_sqdist(d2, ls) * pm
+    mean_n = ops.mean(k_star, alpha)
+    var_n = jnp.maximum(1.0 - ops.sqnorm(chol, k_star), 1e-12)
     std_n = jnp.sqrt(var_n)
 
     # De-standardize.
@@ -80,7 +103,7 @@ def ei_from_sqdist(
 
     improvement = best - mean - xi
     z = improvement / jnp.maximum(std, 1e-12)
-    cdf = 0.5 * (1.0 + jax.scipy.special.erf(z / _SQRT2))
+    cdf = ops.cdf(z)
     pdf = jnp.exp(-0.5 * z * z) / _SQRT2PI
     ei = jnp.maximum(improvement * cdf + std * pdf, 0.0)
     return jnp.where(mask, ei, -jnp.inf)

@@ -36,9 +36,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_kernel_call"]
 
-# JAX 0.4.x spells the Mosaic compiler-params class `TPUCompilerParams`;
-# newer releases renamed it `CompilerParams`.  Accept either.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -164,7 +161,7 @@ def flash_attention_kernel_call(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,8 +1,3 @@
-import os
-
-if "XLA_FLAGS" not in os.environ:  # tool needs the production device count
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Ruya-for-TPU: memory-aware iterative search over execution configurations.
 
 This is the paper's algorithm (``repro.core``) applied beyond its original
@@ -39,6 +34,7 @@ import argparse
 import dataclasses
 import json
 import math
+import os
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -334,4 +330,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.hostdevices import force_host_device_count
+
+    force_host_device_count(512)  # the production mesh's placeholder devices
     main()

@@ -20,7 +20,7 @@ from repro.models.model import Model
 from repro.models.spec import abstract_tree
 from repro.parallel.constraints import activation_sharding
 from repro.parallel.sharding import ShardingRules, default_rules, named_sharding_tree
-from repro.launch.mesh import data_axes, mesh_context, model_axis
+from repro.launch.mesh import data_axes, model_axis
 from repro.runtime.steps import make_serve_steps, make_train_step, train_state_specs
 
 __all__ = ["BuiltCell", "build_cell", "rules_for"]
@@ -44,7 +44,7 @@ class BuiltCell:
             out_shardings=self.out_shardings,
             donate_argnums=self.donate_argnums,
         )
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jitted.lower(*self.abstract_args)
 
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod AOT dry-run: lower + compile every (arch × shape × mesh) cell.
 
 This is how the distribution config is proven coherent without hardware:
@@ -18,11 +15,14 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --mesh multi_pod --skip-existing
 
 Each cell runs in a subprocess so one failure cannot take down the sweep;
-failures are recorded in the artifact with the exception text.
+failures are recorded in the artifact with the exception text.  The
+compiles target 512 placeholder CPU devices, so every cell's process runs
+with ``JAX_PLATFORMS=cpu`` and never contends for an attached accelerator.
 """
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -140,7 +140,8 @@ def main() -> None:
                     [sys.executable, "-m", "repro.launch.dryrun", "--single",
                      "--arch", arch, "--cell", cell, "--mesh", mesh],
                     capture_output=True, text=True,
-                    env={**os.environ, "PYTHONPATH": "src"},
+                    env={**os.environ, "PYTHONPATH": "src",
+                         "JAX_PLATFORMS": "cpu"},
                 )
                 art = None
                 for line in proc.stdout.splitlines():
@@ -175,4 +176,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.hostdevices import force_host_device_count
+
+    force_host_device_count(512)  # the production mesh's placeholder devices
     main()

@@ -18,32 +18,26 @@ from typing import Optional, Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "mesh_context"]
+__all__ = ["make_production_mesh", "make_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(
     shape: Tuple[int, ...], axes: Tuple[str, ...]
 ) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests / the tuner's candidate configurations."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests / the tuner's candidate configurations.
 
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    `jax.set_mesh` only exists from jax 0.6; on the pinned 0.4.37 the
-    `Mesh` object itself is the context manager.  Lowering under the
-    ambient mesh is what lets partially-manual `shard_map`s (auto axes)
-    resolve their automatic dimensions.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    Every axis is Auto: the sharding rules place arrays with
+    `with_sharding_constraint`, which refuses Explicit axes (the
+    `jax.make_mesh` default)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -30,7 +30,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -102,13 +101,11 @@ def moe_apply_shard_map(
                    (fsdp_axes[0] if fsdp_axes else None))
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(x_spec, router_spec, w_in_spec, w_in_spec, w_out_spec),
         out_specs=(x_spec, P()),
-        # jax 0.4.37 spells the disabled varying-/replication-check
-        # `check_rep` (`check_vma` is the jax 0.6 name).
-        check_rep=False,
+        check_vma=False,
     )
     def body(xl, router_l, wg_l, wu_l, wo_l):
         nb, nt, _ = xl.shape

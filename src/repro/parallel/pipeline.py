@@ -29,7 +29,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -54,22 +53,19 @@ def pipeline_apply(
     other_axes = tuple(a for a in mesh.axis_names if a != pod_axis)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             jax.tree.map(lambda _: P(pod_axis), stage_params),
             P(),  # every stage sees the (M, b, ...) input block
         ),
         out_specs=P(),
-        # jax 0.4.37: partially-auto shard_map (manual over pod only,
-        # `axis_names=`/`auto=`) lowers through an unimplemented
-        # PartitionId path on CPU SPMD — so run fully manual over the
-        # mesh: unmentioned axes replicate the operands, which is exactly
-        # the P()-spec'd input block, and the remaining axes ({other_axes})
-        # stay available to explicit collectives inside ``stage_fn``.
-        # Device-varying carries are expressed by disabling the
-        # replication check (`jax.lax.pvary` only exists from jax 0.6).
-        check_rep=False,
+        # Fully manual over the mesh: unmentioned axes replicate the
+        # operands, which is exactly the P()-spec'd input block, and the
+        # remaining axes ({other_axes}) stay available to explicit
+        # collectives inside ``stage_fn``.  The carries are device-varying,
+        # so the varying-manual-axes check is off.
+        check_vma=False,
     )
     def run(params_local, inputs):
         stage = jax.lax.axis_index(pod_axis)

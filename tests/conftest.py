@@ -6,10 +6,9 @@ expose several host devices before it initializes — this module is
 imported before any test module, which makes it the one reliable place to
 set the flag (appended only when the caller has not already forced a
 count).  Single-device numerics do not depend on the forced count: the
-pre-PR-5 tier-1 process already ran with 512 forced devices whenever
-XLA_FLAGS was unset (`repro.launch.autotune` sets it at collection time —
-its guard now never fires in-process because this file runs first), and
-the full suite passes identically at 4.  Subprocess suites (sharding
+pre-PR-5 tier-1 process ran with 512 forced devices whenever XLA_FLAGS
+was unset, and the full suite passes identically at 4.  Subprocess
+suites (sharding
 equivalence, tiny-mesh dry-runs) still spawn with their own XLA_FLAGS via
 `run_with_devices`.
 

@@ -15,9 +15,12 @@ while the reference lane demonstrably has both.
 
 Everything here runs on the CPU test topology (interpret mode executes
 the kernel body as ordinary XLA:CPU ops); the compiled-TPU lane shares
-the same body with a forward-substitution solve and is covered by the
-same calls when a TPU backend is present.
+the same body with Mosaic-lowerable tail ops, which `TestMosaicTailOps`
+holds to the XLA ops within float32 tolerance, and is covered by the same
+calls when a TPU backend is present.
 """
+
+import math
 
 import numpy as np
 import pytest
@@ -27,8 +30,11 @@ import jax.numpy as jnp
 
 from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 from repro.core.fast_bo import bo_step_core, bo_step_core_fused
+from repro.core.gp import matern52_from_sqdist, pairwise_sqdist
 from repro.kernels.ei_argmax import ei_argmax
+from repro.kernels.ei_argmax.kernel import MOSAIC_TAIL_OPS, _sqdist_t
 from repro.kernels.ei_argmax.ops import _pick_tile
+from repro.kernels.ei_argmax.tile import XLA_TAIL_OPS
 
 pytestmark = pytest.mark.kernel
 
@@ -247,3 +253,54 @@ class TestNoCrossBlock:
             f"fused transients {fused}B vs reference {ref}B — "
             f"expected >=8x reduction"
         )
+
+
+class TestMosaicTailOps:
+    """The compiled-TPU kernel's stand-ins (`MOSAIC_TAIL_OPS`, the
+    difference-form distance block) against the XLA ops they replace, run
+    here as ordinary XLA:CPU ops: interpret mode never executes them, and
+    the TPU compile tests only show that they lower."""
+
+    B, M, D = 24, 512, 6
+
+    def _gp(self):
+        rng = np.random.default_rng(5)
+        feats = rng.normal(size=(self.B, self.D)).astype(np.float32)
+        enc = rng.normal(size=(self.M, self.D)).astype(np.float32)
+        d2 = np.asarray(pairwise_sqdist(jnp.asarray(feats), jnp.asarray(feats)))
+        k = np.asarray(matern52_from_sqdist(jnp.asarray(d2), 2.0))
+        chol = np.linalg.cholesky(k + 1e-2 * np.eye(self.B)).astype(np.float32)
+        k_star = np.asarray(matern52_from_sqdist(
+            pairwise_sqdist(jnp.asarray(feats), jnp.asarray(enc)), 2.0))
+        return feats, enc, chol, k_star
+
+    @pytest.mark.parametrize("op", ["cdf", "sqnorm", "mean", "sqdist"])
+    def test_matches_xla_op(self, op):
+        feats, enc, chol, k_star = self._gp()
+        if op == "cdf":
+            z = np.linspace(-9.0, 6.0, 4001).astype(np.float32)
+            want = np.asarray([0.5 * math.erfc(-float(v) / math.sqrt(2.0))
+                               for v in z])
+            got = np.asarray(MOSAIC_TAIL_OPS.cdf(jnp.asarray(z)), np.float64)
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+            return
+        if op == "sqnorm":
+            # The upper triangle is never read: garbage there is inert.
+            garbage = chol + np.triu(np.full_like(chol, np.nan), 1)
+            got = MOSAIC_TAIL_OPS.sqnorm(jnp.asarray(garbage),
+                                         jnp.asarray(k_star))
+            want = XLA_TAIL_OPS.sqnorm(jnp.asarray(chol), jnp.asarray(k_star))
+            np.testing.assert_allclose(np.asarray(got)[0], want, rtol=1e-5)
+            return
+        if op == "mean":
+            alpha = np.random.default_rng(6).normal(size=self.B).astype(
+                np.float32)
+            got = MOSAIC_TAIL_OPS.mean(jnp.asarray(k_star),
+                                       jnp.asarray(alpha[:, None]))
+            want = XLA_TAIL_OPS.mean(jnp.asarray(k_star), jnp.asarray(alpha))
+            np.testing.assert_allclose(np.asarray(got)[0], want, rtol=1e-5,
+                                       atol=1e-6)
+            return
+        got = _sqdist_t(jnp.asarray(feats), jnp.asarray(enc.T))
+        want = pairwise_sqdist(jnp.asarray(feats), jnp.asarray(enc))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -66,15 +66,12 @@ class TestCollectives:
             """
             import jax, jax.numpy as jnp
             from functools import partial
-            # jax.shard_map only exists from jax 0.6; on the pinned 0.4.37
-            # the stable spelling is jax.experimental.shard_map.shard_map.
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.launch.hlo_analysis import analyze_hlo
 
             mesh = jax.make_mesh((8,), ("x",))
 
-            @partial(shard_map, mesh=mesh, in_specs=P("x"), out_specs=P())
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P())
             def f(v):
                 return jax.lax.psum(v, "x")
 
@@ -93,20 +90,16 @@ class TestCollectives:
             """
             import jax, jax.numpy as jnp
             from functools import partial
-            # jax.shard_map and jax.lax.pvary only exist from jax 0.6; on
-            # the pinned 0.4.37 use jax.experimental.shard_map.shard_map,
-            # and carry the psum result directly — without pvary to devary
-            # the replicated carry, the replication checker would reject
-            # the scan body, so it is disabled (check_rep=False; the HLO
-            # under test is identical).
-            from jax.experimental.shard_map import shard_map
+            # The psum result is carried directly; the varying-manual-axes
+            # checker would reject the replicated carry, so it is off (the
+            # HLO under test is identical).
             from jax.sharding import PartitionSpec as P
             from repro.launch.hlo_analysis import analyze_hlo
 
             mesh = jax.make_mesh((8,), ("x",))
 
-            @partial(shard_map, mesh=mesh, in_specs=P("x"), out_specs=P(),
-                     check_rep=False)
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("x"),
+                     out_specs=P(), check_vma=False)
             def step(v):
                 def body(c, _):
                     y = jax.lax.psum(c, "x") * (1.0 / 8.0)

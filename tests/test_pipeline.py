@@ -10,9 +10,10 @@ class TestPipeline:
         out = devices_runner(
             """
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.parallel.pipeline import pipeline_apply
 
-            mesh = jax.make_mesh((2, 4), ("pod", "data"))
+            mesh = make_mesh((2, 4), ("pod", "data"))
             L, M, B, D = 4, 3, 2, 8  # 4 layers → 2 stages of 2
             key = jax.random.key(0)
             w = jax.random.normal(key, (L, D, D)) * 0.3
@@ -58,8 +59,9 @@ class TestPipeline:
         out = devices_runner(
             """
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.parallel.pipeline import pipeline_apply
-            mesh = jax.make_mesh((1, 8), ("pod", "data"))
+            mesh = make_mesh((1, 8), ("pod", "data"))
             w = jax.random.normal(jax.random.key(0), (2, 4, 4)) * 0.3
             xs = jax.random.normal(jax.random.key(1), (2, 3, 4))
 

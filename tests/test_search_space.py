@@ -1,6 +1,7 @@
 """Search-space split invariants (paper §III-D) — unit + hypothesis — and
-the host↔device split identity that lets `TuningSession` narrow on device
-while staying bit-identical to the host-split drivers."""
+the loop↔vectorized split identity that lets `TuningSession` narrow a
+whole catalog at once while staying bit-identical to the host-split
+drivers."""
 
 import numpy as np
 import pytest
@@ -10,7 +11,7 @@ from repro.core.memory_model import MemoryCategory, MemoryModel, fit_memory_mode
 from repro.core.search_space import (
     Configuration,
     SearchSpace,
-    split_masks_device,
+    split_priority_mask,
     split_search_space,
 )
 
@@ -105,14 +106,14 @@ class TestSplitProperties:
 
 def assert_masks_match_host(space, model, input_size, **kw):
     prio, rest = split_search_space(space, model, input_size, **kw)
-    mask = np.asarray(split_masks_device(space, model, input_size, **kw))
+    mask = split_priority_mask(space, model, input_size, **kw)
     assert mask.dtype == bool and mask.shape == (len(space),)
     assert list(np.flatnonzero(mask)) == prio
     assert list(np.flatnonzero(~mask)) == rest
 
 
 class TestDeviceSplitIdentity:
-    """`split_masks_device` (float64 on device, stable sort) must reproduce
+    """`split_priority_mask` (vectorized float64, stable sort) must reproduce
     `split_search_space` EXACTLY — the priority mask is the sorted-index
     host split bit-for-bit, every category and fallback included."""
 
@@ -145,8 +146,9 @@ class TestDeviceSplitIdentity:
 
     def test_borderline_requirement_equality(self):
         """Configs whose memory EQUALS the float64 requirement must land on
-        the same side of the ≥ as the host rule (this is what float32-on-
-        device could get wrong, and why the device split runs in float64)."""
+        the same side of the ≥ as the host rule (this is what float32 — or
+        a TPU's float32-pair emulation of float64 — could get wrong, and
+        why the split runs in IEEE float64 on the host)."""
         model = model_with(MemoryCategory.LINEAR, slope=3.0,
                            intercept=1.23456789e9)
         inp = 17.123456789e9

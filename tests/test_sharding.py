@@ -82,8 +82,7 @@ class TestResolvePspec:
             state2 = init_train_state(model, ex, jax.random.key(0))
             jitted = jax.jit(built.step_fn, in_shardings=built.in_shardings,
                              out_shardings=built.out_shardings)
-            from repro.launch.mesh import mesh_context
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 _, m2 = jitted(state2, batch)
             l1, l2 = float(m1["loss"]), float(m2["loss"])
             print("LOSSES", l1, l2)
