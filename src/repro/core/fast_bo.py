@@ -283,6 +283,7 @@ def _masked_posterior(
     return lml, mean_n, var_n
 
 
+@jax.named_scope("gp_head")
 def _packed_head(
     d2_bb: jax.Array,  # (B, B) raw squared distances, training block
     py: jax.Array,  # (B,) f32 packed observed costs, trial order
@@ -300,6 +301,10 @@ def _packed_head(
 
     Returns ``(pm, best, ls_sel, chol, alpha, y_mean, y_std)``: the
     selected posterior factors the EI tail consumes.
+
+    Traced under the ``gp_head`` name scope, so that a profile can
+    attribute the device time of its operations; the scope is metadata
+    and changes no numerics.
     """
     b = py.shape[0]
     pmask = jnp.arange(b) < t

@@ -12,7 +12,7 @@ own host thread driving its own jitted dispatch loop at its own pace,
                                             # it at ITS next iteration
                                             # boundary and steps it
     service.drain()                         # block until everything lands
-    service.metrics()                       # per-group latency, queue
+    service.metrics()                       # per-group counters, queue
                                             # depth, jobs/sec, fault totals
     service.shutdown(drain=True)
 
@@ -57,6 +57,7 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 import jax
 
 from repro.fleet.session import JobHandle, SearchOutcome, TuningSession
+from repro.fleet.telemetry import span
 
 __all__ = ["ServiceSaturated", "TuningService"]
 
@@ -68,27 +69,23 @@ class ServiceSaturated(RuntimeError):
 
 
 class _GroupStats:
-    """Per-group metrics, mutated by the owning worker under the CV."""
+    """Per-group metrics, mutated by the owning worker under the CV.  The
+    session's own counters of the group (`repro.fleet.telemetry`) are
+    merged in by `TuningService.metrics`."""
 
-    __slots__ = ("iterations", "steps", "last_step_s", "total_step_s",
-                 "admitted", "device")
+    __slots__ = ("iterations", "steps", "admitted", "device")
 
     def __init__(self, device: Optional[str]) -> None:
         self.iterations = 0
         self.steps = 0
-        self.last_step_s = 0.0
-        self.total_step_s = 0.0
         self.admitted = 0
         self.device = device
 
     def as_dict(self) -> dict:
-        mean = self.total_step_s / self.steps if self.steps else 0.0
         return {
             "iterations": self.iterations,
             "steps": self.steps,
             "admitted": self.admitted,
-            "last_step_s": self.last_step_s,
-            "mean_step_s": mean,
             "device": self.device,
         }
 
@@ -150,14 +147,9 @@ class _GroupWorker(threading.Thread):
                 for ch in chunks:
                     if svc._halt:
                         return
-                    t0 = time.monotonic()
                     session._step_chunk(ch)
-                    dt = time.monotonic() - t0
                     with svc._cv:
-                        st = svc._stats[self.key]
-                        st.steps += 1
-                        st.last_step_s = dt
-                        st.total_step_s += dt
+                        svc._stats[self.key].steps += 1
                 with svc._cv:
                     svc._stats[self.key].iterations += 1
         except BaseException as e:  # surface in drain(), don't die silently
@@ -358,7 +350,7 @@ class TuningService:
         self._ensure_workers()
 
     def _idle_wait(self, timeout: float = 0.005) -> None:
-        with self._cv:
+        with span("tuning.idle"), self._cv:
             if not self._halt:
                 self._cv.wait(timeout)
 
@@ -433,15 +425,22 @@ class TuningService:
     def metrics(self) -> dict:
         """JSON-able operational snapshot: queue depth, in-flight count,
         sustained jobs/sec (completions over the first-submit→last-
-        completion window), per-group step latency/iteration counts, and
-        the fleet's fault/retry totals (profiling attempts incl. retries,
-        charged backoff seconds, straggler-flagged trials — the PR-7
-        counters, aggregated from published outcomes)."""
-        with self._session._lock:
-            queue_depth = len(self._session._pending)
+        completion window), per-group iteration counts and the session's
+        per-group counters (dispatches, done-flag polls, non-empty and
+        empty admissions, and the host seconds spent admitting,
+        dispatching, waiting on polls and retiring), the session lock's
+        contended waits, and the fleet's fault/retry totals (profiling
+        attempts incl. retries, charged backoff seconds, straggler-flagged
+        trials — aggregated from published outcomes)."""
+        session = self._session
+        with session._lock:
+            queue_depth = len(session._pending)
             live_chunks: Dict[tuple, int] = {}
-            for ch in self._session._chunks:
+            for ch in session._chunks:
                 live_chunks[ch.group_key] = live_chunks.get(ch.group_key, 0) + 1
+            counters = session.telemetry.groups()
+            lock_waits = session.telemetry.lock_waits
+            lock_wait_s = session.telemetry.lock_wait_s
         with self._cv:
             # Sustained rate only over a real window: `is not None` (a
             # monotonic stamp CAN be 0.0 — truthiness silently dropped the
@@ -461,6 +460,7 @@ class TuningService:
             groups = {}
             for key, st in self._stats.items():
                 g = st.as_dict()
+                g.update(counters.get(key, {}))
                 g["live_chunks"] = live_chunks.get(key, 0)
                 g["worker_alive"] = key in self._workers
                 groups[str(key)] = g
@@ -476,6 +476,8 @@ class TuningService:
                     None if span is None else self._completed / span
                 ),
                 "statuses": dict(self._status_counts),
+                "lock_waits": lock_waits,
+                "lock_wait_s": lock_wait_s,
                 "faults": {
                     "profile_attempts_total": self._profile_attempts_total,
                     "profile_retries_total": (

@@ -63,7 +63,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import threading
+import time
 import weakref
 from typing import (
     Callable, Dict, List, Optional, Sequence, Set, Tuple, TYPE_CHECKING,
@@ -102,6 +102,7 @@ from repro.fleet.sharding import (
     resolve_shard_devices,
     sharded_update,
 )
+from repro.fleet.telemetry import Telemetry, TimedLock, recording, span
 
 if TYPE_CHECKING:  # import cycle: driver imports session for tune_fleet
     from repro.fleet.driver import FleetJob
@@ -722,9 +723,12 @@ class TuningSession:
         # (`_step_chunk` captures the state ref under the lock, then blocks
         # on the device queue unlocked), so a slow group's compute never
         # stalls another group's dispatch.  The single-threaded paths
-        # (`step()`/`drain()`) take the same lock — uncontended acquisition
-        # is nanoseconds against millisecond-scale chunk steps.
-        self._lock = threading.RLock()
+        # (`step()`/`drain()`) take the same lock — an uncontended
+        # acquisition is a non-blocking try, well under a microsecond
+        # against millisecond-scale chunk steps; a contended one is timed
+        # into `telemetry` (`repro.fleet.telemetry`).
+        self.telemetry = Telemetry()
+        self._lock = TimedLock(self.telemetry)
         # Called (under the lock) with each published SearchOutcome — the
         # service hooks this for completion signalling and metrics.
         self._outcome_listeners: List[Callable[[SearchOutcome], None]] = []
@@ -790,12 +794,16 @@ class TuningSession:
         pending-queue append are one atomic unit — a submission is a
         deterministic function of the class history it observed).
         """
-        with self._lock:
-            return self._submit_locked(
-                job, rng, seed=seed, mode=mode, priority=priority,
-                remaining=remaining, warm_start=warm_start,
-                job_priority=job_priority, objective=objective,
-            )
+        with span("tuning.submit") as sp:
+            with self._lock:
+                handle = self._submit_locked(
+                    job, rng, seed=seed, mode=mode, priority=priority,
+                    remaining=remaining, warm_start=warm_start,
+                    job_priority=job_priority, objective=objective,
+                )
+            if recording():
+                sp.set_metadata(uid=handle.uid)
+            return handle
 
     def _submit_locked(
         self,
@@ -1025,32 +1033,44 @@ class TuningSession:
         own iteration boundary.  ``device`` pins the new chunks' buffers
         (and therefore their compute) to one device, letting the service
         spread groups across the host topology; None keeps the default
-        placement.  Returns the number of jobs admitted."""
+        placement.  Returns the number of jobs admitted.
+
+        Only a non-empty admission opens a ``tuning.admit`` span and adds
+        to ``admit_s``; an empty poll just counts."""
+        counters = self.telemetry.group(key)
         with self._lock:
             members = [
                 rec for rec in self._pending
                 if (rec.enc.shape, rec.budget) == key
             ]
             if not members:
+                counters.empty_admissions += 1
                 return 0
-            self._pending = [
-                rec for rec in self._pending
-                if (rec.enc.shape, rec.budget) != key
-            ]
-            shape, cap = key
-            n_init_slots = max(1, max(len(r.init_list) for r in members))
-            if self.shard_devices is not None:
-                self._chunks.extend(
-                    self._build_sharded(members, shape, cap, n_init_slots)
-                )
-                return len(members)
-            for lo in range(0, len(members), _CHUNK):
-                self._chunks.append(
-                    self._build_chunk(
-                        members[lo : lo + _CHUNK], shape, cap, n_init_slots,
-                        device=device,
+            t0 = time.perf_counter()
+            with span("tuning.admit") as sp:
+                self._pending = [
+                    rec for rec in self._pending
+                    if (rec.enc.shape, rec.budget) != key
+                ]
+                shape, cap = key
+                n_init_slots = max(1, max(len(r.init_list) for r in members))
+                if self.shard_devices is not None:
+                    chunks = self._build_sharded(
+                        members, shape, cap, n_init_slots
                     )
-                )
+                else:
+                    chunks = [
+                        self._build_chunk(
+                            members[lo : lo + _CHUNK], shape, cap,
+                            n_init_slots, device=device,
+                        )
+                        for lo in range(0, len(members), _CHUNK)
+                    ]
+                self._chunks.extend(chunks)
+                if recording():
+                    sp.set_metadata(rows=len(members), chunks=len(chunks))
+            counters.admissions += 1
+            counters.admit_s += time.perf_counter() - t0
             return len(members)
 
     def _step_chunk(self, ch: "_LiveChunk") -> str:
@@ -1068,14 +1088,24 @@ class TuningSession:
         Device WAITS (the done-flag poll, the pre-retirement sync) happen
         OUTSIDE the lock on a captured state reference: only this chunk's
         owner ever advances it, so the captured buffers cannot be donated
-        from under the wait."""
+        from under the wait.
+
+        Three spans, each with its counter: ``tuning.dispatch`` (the
+        enqueue of the update), ``tuning.poll`` (the done-flag sync) and
+        ``tuning.retire`` (the pre-retirement sync, `_retire` and the
+        publishes)."""
+        counters = self.telemetry.group(ch.group_key)
         with self._lock:
             if ch not in self._chunks:
                 return "gone"
             if all(m is None for m in ch.members):
                 self._chunks.remove(ch)
                 return "dead"
-            ch.state = ch.update(ch.state, ch.args)
+            t0 = time.perf_counter()
+            with span("tuning.dispatch"):
+                ch.state = ch.update(ch.state, ch.args)
+            counters.dispatches += 1
+            counters.dispatch_s += time.perf_counter() - t0
             ch.steps_done += 1
             retire = ch.steps_done >= ch.steps_needed
             poll = (
@@ -1086,16 +1116,25 @@ class TuningSession:
             done_flags = ch.state.done if (poll or retire) else None
         if poll:
             # Blocks on this chunk's device queue only.
-            retire = bool(jnp.all(done_flags))
+            t0 = time.perf_counter()
+            with span("tuning.poll"):
+                retire = bool(jnp.all(done_flags))
+            counters.polls += 1
+            counters.poll_wait_s += time.perf_counter() - t0
         if not retire:
             return "stepped"
-        jax.block_until_ready(done_flags)
-        with self._lock:
-            if ch not in self._chunks:
-                return "gone"
-            self._retire(ch)
-            self._chunks.remove(ch)
-            return "retired"
+        t0 = time.perf_counter()
+        try:
+            with span("tuning.retire", rows=len(ch.members)):
+                jax.block_until_ready(done_flags)
+                with self._lock:
+                    if ch not in self._chunks:
+                        return "gone"
+                    self._retire(ch)
+                    self._chunks.remove(ch)
+                    return "retired"
+        finally:
+            counters.retire_s += time.perf_counter() - t0
 
     def drain(self) -> List[SearchOutcome]:
         """Step until every submitted job has finished; returns all outcomes
@@ -1447,25 +1486,10 @@ class TuningSession:
         `batched_search`, so a statically submitted fleet compiles and runs
         the identical array program.  With sharding on, each group's chunks
         are instead bundled across the shard devices (`_build_sharded`)."""
-        if not self._pending:
-            return
-        groups: Dict[tuple, List[_JobRec]] = {}
-        for rec in self._pending:
-            groups.setdefault((rec.enc.shape, rec.budget), []).append(rec)
-        self._pending = []
-        for (shape, cap), members in groups.items():
-            n_init_slots = max(1, max(len(r.init_list) for r in members))
-            if self.shard_devices is not None:
-                self._chunks.extend(
-                    self._build_sharded(members, shape, cap, n_init_slots)
-                )
-                continue
-            for lo in range(0, len(members), _CHUNK):
-                self._chunks.append(
-                    self._build_chunk(
-                        members[lo : lo + _CHUNK], shape, cap, n_init_slots
-                    )
-                )
+        for key in dict.fromkeys(
+            (rec.enc.shape, rec.budget) for rec in self._pending
+        ):
+            self._admit_group(key)
 
     def _build_sharded(
         self, members: List[_JobRec], shape, cap: int, n_init_slots: int,
@@ -1495,35 +1519,37 @@ class TuningSession:
                     self._build_chunk(sl, shape, cap, n_init_slots, resume=rs)
                 )
                 continue
-            parts = [
-                self._chunk_arrays(
-                    sl[k * rows : (k + 1) * rows], shape, cap, n_init_slots,
-                    rows,
-                    resume=(
-                        None if rs is None
-                        else rs[k * rows : (k + 1) * rows]
-                    ),
-                )
-                for k in range(n_shards)
-            ]
+            with span("tuning.chunk_arrays"):
+                parts = [
+                    self._chunk_arrays(
+                        sl[k * rows : (k + 1) * rows], shape, cap,
+                        n_init_slots, rows,
+                        resume=(
+                            None if rs is None
+                            else rs[k * rows : (k + 1) * rows]
+                        ),
+                    )
+                    for k in range(n_shards)
+                ]
             update, sharding = sharded_update(
                 self.shard_devices[:n_shards], self.settings.xi, self.layout
             )
-            state = jax.tree_util.tree_map(
-                lambda *xs: jax.device_put(np.stack(xs), sharding),
-                *[p[0] for p in parts],
-            )
-            args = tuple(
-                jax.device_put(np.stack(xs), sharding)
-                for xs in zip(*[p[1] for p in parts])
-            ) + tuple(
-                jax.device_put(np.stack([v] * n_shards), sharding)
-                for v in (
-                    np.asarray(self.settings.min_observations, np.int32),
-                    np.asarray(self.settings.ei_stop_rel, np.float32),
-                    np.asarray(self.to_exhaustion),
+            with span("tuning.device_put"):
+                state = jax.tree_util.tree_map(
+                    lambda *xs: jax.device_put(np.stack(xs), sharding),
+                    *[p[0] for p in parts],
                 )
-            )
+                args = tuple(
+                    jax.device_put(np.stack(xs), sharding)
+                    for xs in zip(*[p[1] for p in parts])
+                ) + tuple(
+                    jax.device_put(np.stack([v] * n_shards), sharding)
+                    for v in (
+                        np.asarray(self.settings.min_observations, np.int32),
+                        np.asarray(self.settings.ei_stop_rel, np.float32),
+                        np.asarray(self.to_exhaustion),
+                    )
+                )
             out.append(
                 _LiveChunk(
                     state=state,
@@ -1543,30 +1569,33 @@ class TuningSession:
         resume: Optional[List[FleetState]] = None,
         device=None,
     ) -> _LiveChunk:
-        state_np, args_np, steps_needed = self._chunk_arrays(
-            members, shape, cap, n_init_slots, max(len(members), 2),
-            resume=resume,
-        )
+        with span("tuning.chunk_arrays"):
+            state_np, args_np, steps_needed = self._chunk_arrays(
+                members, shape, cap, n_init_slots, max(len(members), 2),
+                resume=resume,
+            )
         tail_np = (
             np.asarray(self.settings.min_observations, np.int32),
             np.asarray(self.settings.ei_stop_rel, np.float32),
             np.asarray(self.to_exhaustion),
         )
-        if device is None:
-            state = jax.tree_util.tree_map(jnp.asarray, state_np)
-            args = tuple(jnp.asarray(a) for a in args_np) + tuple(
-                jnp.asarray(v) for v in tail_np
-            )
-        else:
-            # Committed placement: the jitted update runs on ``device``
-            # (identical program and numerics on the identical-ISA host
-            # devices — only WHERE it executes changes, which is how the
-            # service spreads group threads across the forced topology).
-            put = lambda x: jax.device_put(np.asarray(x), device)
-            state = jax.tree_util.tree_map(put, state_np)
-            args = tuple(put(a) for a in args_np) + tuple(
-                put(v) for v in tail_np
-            )
+        with span("tuning.device_put"):
+            if device is None:
+                state = jax.tree_util.tree_map(jnp.asarray, state_np)
+                args = tuple(jnp.asarray(a) for a in args_np) + tuple(
+                    jnp.asarray(v) for v in tail_np
+                )
+            else:
+                # Committed placement: the jitted update runs on ``device``
+                # (identical program and numerics on the identical-ISA host
+                # devices — only WHERE it executes changes, which is how
+                # the service spreads group threads across the forced
+                # topology).
+                put = lambda x: jax.device_put(np.asarray(x), device)
+                state = jax.tree_util.tree_map(put, state_np)
+                args = tuple(put(a) for a in args_np) + tuple(
+                    put(v) for v in tail_np
+                )
         xi, layout = self.settings.xi, self.layout
         return _LiveChunk(
             state=state,

@@ -1,0 +1,126 @@
+"""Spans and counters of the tuning service.
+
+Spans are `jax.profiler.TraceAnnotation`s named ``tuning.*``: while a
+profiler trace records (`jax.profiler.trace` around a window), they land
+on the host threads that ran them, on the same clock as the device's
+"XLA Ops", and nesting on one thread gives the parent.  Off, a span costs
+one inactive annotation (well under a microsecond); its keyword
+arguments are attached only while a trace records.
+
+    span                 where (repro.fleet)          covers
+    tuning.submit        TuningSession.submit         profile, split, enqueue
+    tuning.admit         TuningSession._admit_group   a non-empty admission
+    tuning.chunk_arrays  _build_chunk/_build_sharded  host state and args
+    tuning.device_put    _build_chunk/_build_sharded  their transfers
+    tuning.dispatch      TuningSession._step_chunk    enqueue of one update
+    tuning.poll          TuningSession._step_chunk    the done-flag sync
+    tuning.retire        TuningSession._step_chunk    sync, retire, publish
+    tuning.lock_wait     the session lock             a contended acquire
+    tuning.idle          TuningService._idle_wait     a worker with no work
+
+Counters are always on and are timed with `time.perf_counter` at the
+boundaries of the matching span: per admission group (`GroupCounters`)
+and session-wide (`Telemetry`, the lock waits).  `TuningService.metrics()`
+reports them.
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from typing import Dict
+
+from jax.profiler import TraceAnnotation
+
+__all__ = ["GroupCounters", "Telemetry", "TimedLock", "recording", "span"]
+
+recording = TraceAnnotation.is_enabled
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A ``with`` span on the profiler's clock; ``args`` are attached
+    only while a trace records."""
+    if args and recording():
+        return TraceAnnotation(name, **args)
+    return TraceAnnotation(name)
+
+
+class GroupCounters:
+    """One admission group's counters.  Each group is advanced by one
+    thread at a time (its service worker, or the caller of `step()`), so
+    the fields are plain attributes."""
+
+    __slots__ = ("dispatches", "polls", "admissions", "empty_admissions",
+                 "admit_s", "dispatch_s", "poll_wait_s", "retire_s")
+
+    def __init__(self) -> None:
+        self.dispatches = 0  # update enqueues (`tuning.dispatch`)
+        self.polls = 0  # done-flag syncs (`tuning.poll`)
+        self.admissions = 0  # non-empty admissions (`tuning.admit`)
+        self.empty_admissions = 0  # admission polls that found nothing
+        self.admit_s = 0.0
+        self.dispatch_s = 0.0
+        self.poll_wait_s = 0.0
+        self.retire_s = 0.0
+
+    def as_dict(self) -> dict:
+        return {f: getattr(self, f) for f in self.__slots__}
+
+
+class Telemetry:
+    """A session's counters: one `GroupCounters` per admission group,
+    and the session lock's contended waits."""
+
+    def __init__(self) -> None:
+        self._groups: Dict[tuple, GroupCounters] = {}
+        self._new = threading.Lock()
+        self.lock_waits = 0
+        self.lock_wait_s = 0.0
+
+    def group(self, key: tuple) -> GroupCounters:
+        g = self._groups.get(key)
+        if g is None:
+            with self._new:
+                g = self._groups.setdefault(key, GroupCounters())
+        return g
+
+    def groups(self) -> Dict[tuple, dict]:
+        with self._new:
+            items = list(self._groups.items())
+        return {k: g.as_dict() for k, g in items}
+
+
+class TimedLock:
+    """The session's re-entrant lock, recording contended acquisitions.
+
+    A non-blocking try comes first; only when it fails is the wait
+    timed, inside a ``tuning.lock_wait`` span.  The counters are updated
+    once the lock is held, so they need no lock of their own."""
+
+    __slots__ = ("_lock", "_telemetry")
+
+    def __init__(self, telemetry: Telemetry) -> None:
+        self._lock = threading.RLock()
+        self._telemetry = telemetry
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock.acquire(False):
+            return True
+        if not blocking:
+            return False
+        t0 = time.perf_counter()
+        with TraceAnnotation("tuning.lock_wait"):
+            got = self._lock.acquire(True, timeout)
+        if got:
+            self._telemetry.lock_waits += 1
+            self._telemetry.lock_wait_s += time.perf_counter() - t0
+        return got
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def __enter__(self) -> bool:
+        return self.acquire()
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()

@@ -409,7 +409,13 @@ class TestMetricsSurface:
         assert len(groups) == 1  # one admission group in this workload
         (g,) = groups.values()
         assert g["iterations"] > 0 and g["steps"] > 0
-        assert g["mean_step_s"] > 0 and g["last_step_s"] > 0
+        # Every step that found its chunk live enqueued one update.
+        assert 0 < g["dispatches"] <= g["steps"]
+        assert g["admit_s"] > 0 and g["dispatch_s"] > 0
+        assert g["admissions"] >= 1
+        assert g["polls"] >= 0 and g["poll_wait_s"] >= 0.0
+        assert g["retire_s"] > 0
+        assert m["lock_waits"] >= 0 and m["lock_wait_s"] >= 0.0
         assert g["admitted"] == 3
         assert g["live_chunks"] == 0
 

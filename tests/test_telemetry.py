@@ -1,0 +1,245 @@
+"""The tuning service's spans and counters (`repro.fleet.telemetry`).
+
+Runs the service under `jax.profiler.trace` into a temporary directory
+and reads the ``tuning.*`` spans back from the recorded ``.xplane.pb``
+with `jax.profiler.ProfileData`: which spans appear, how they nest on
+their thread, and that each counter of `TuningService.metrics()` agrees
+with its span.
+"""
+
+import glob
+import json
+import os
+import sys
+import threading
+import time
+
+import jax
+import pytest
+from jax.profiler import ProfileData
+
+from repro.core.bayesopt import BOSettings
+from repro.fleet import FleetJob, TuningService, TuningSession
+from repro.fleet.telemetry import Telemetry, TimedLock
+
+from golden.scenarios import synth_space_table
+
+pytestmark = pytest.mark.service
+
+
+def _session_kwargs():
+    return dict(layout="feature", settings=BOSettings(max_iters=10),
+                warm_start=False)
+
+
+def _spans(log_dir):
+    """Every ``tuning.*`` span of the trace as a dict with its thread
+    (line), its interval, its arguments and its enclosing spans on the
+    same thread (innermost first)."""
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line_no, line in enumerate(plane.lines):
+            evs = sorted(
+                ((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                  dict(e.stats)) for e in line.events
+                 if e.name.startswith("tuning.")),
+                key=lambda ev: (ev[0], -ev[1]))
+            stack = []
+            for s, e, name, args in evs:
+                while stack and stack[-1]["end"] <= s:
+                    stack.pop()
+                sp = {"name": name, "thread": (plane.name, line_no),
+                      "start": s, "end": e, "args": args,
+                      "parents": [p["name"] for p in reversed(stack)]}
+                out.append(sp)
+                stack.append(sp)
+    return out
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+class TestSpans:
+    def test_two_group_service_spans_nest_as_documented(self, tmp_path):
+        small, small_table = synth_space_table(24)
+        large, large_table = synth_space_table(69)
+        svc = TuningService(**_session_kwargs())
+        with jax.profiler.trace(str(tmp_path)):
+            svc.pause()
+            for s in range(3):
+                for tag, space, table in (("a", small, small_table),
+                                          ("b", large, large_table)):
+                    svc.submit(FleetJob(name=f"{tag}{s}", space=space,
+                                        cost_table=table),
+                               seed=s, mode="cherrypick")
+            time.sleep(0.05)  # the paused workers idle
+            svc.drain()
+            # Joins the workers, so that every span has closed.
+            svc.shutdown(drain=False)
+        m = svc.metrics()
+        json.dumps(m)  # the operator's surface stays JSON-able
+        spans = _spans(str(tmp_path))
+
+        names = {s["name"] for s in spans}
+        assert names >= {"tuning.submit", "tuning.admit",
+                         "tuning.chunk_arrays", "tuning.device_put",
+                         "tuning.dispatch", "tuning.poll", "tuning.retire",
+                         "tuning.idle"}
+        assert len(_named(spans, "tuning.submit")) == 6
+        assert sorted(s["args"]["uid"]
+                      for s in _named(spans, "tuning.submit")) == list(
+                          range(6))
+        for name in ("tuning.chunk_arrays", "tuning.device_put"):
+            assert all("tuning.admit" in s["parents"]
+                       for s in _named(spans, name))
+        for name in ("tuning.poll", "tuning.retire"):
+            assert all("tuning.dispatch" not in s["parents"]
+                       for s in _named(spans, name))
+        admits = _named(spans, "tuning.admit")
+        assert sum(s["args"]["rows"] for s in admits) == 6
+        assert all(s["args"]["chunks"] == 1 for s in admits)
+
+        groups = m["groups"]
+        assert len(groups) == 2
+        # Each counter counts exactly the spans opened at its boundary.
+        assert sum(g["dispatches"] for g in groups.values()) == len(
+            _named(spans, "tuning.dispatch"))
+        assert sum(g["polls"] for g in groups.values()) == len(
+            _named(spans, "tuning.poll"))
+        assert sum(g["admissions"] for g in groups.values()) == len(admits)
+        for g in groups.values():
+            assert g["dispatches"] > 0 and g["dispatch_s"] > 0
+            assert g["admissions"] >= 1 and g["admit_s"] > 0
+            assert g["retire_s"] > 0 and g["poll_wait_s"] >= 0
+        assert len(_named(spans, "tuning.lock_wait")) == m["lock_waits"]
+
+    def test_empty_admission_emits_no_span(self, tmp_path):
+        session = TuningSession(**_session_kwargs())
+        key = ((24, 5), 10)
+        with jax.profiler.trace(str(tmp_path)):
+            assert session._admit_group(key) == 0
+        assert _named(_spans(str(tmp_path)), "tuning.admit") == []
+        counters = session.telemetry.groups()[key]
+        assert counters["empty_admissions"] == 1
+        assert counters["admissions"] == 0 and counters["admit_s"] == 0.0
+
+    def test_blocked_submit_records_one_lock_wait(self, tmp_path):
+        session = TuningSession(**_session_kwargs())
+        space, table = synth_space_table(24)
+        held, release = threading.Event(), threading.Event()
+
+        def worker():
+            with session._lock:
+                held.set()
+                release.wait(10.0)
+
+        def submitter():
+            session.submit(FleetJob(name="late", space=space,
+                                    cost_table=table),
+                           seed=0, mode="cherrypick")
+
+        with jax.profiler.trace(str(tmp_path)):
+            holder = threading.Thread(target=worker)
+            holder.start()
+            assert held.wait(10.0)
+            sub = threading.Thread(target=submitter)
+            sub.start()
+            time.sleep(0.05)
+            assert sub.is_alive()  # parked on the session lock
+            release.set()
+            sub.join(10.0)
+            holder.join(10.0)
+        assert not sub.is_alive() and not holder.is_alive()
+        assert session.telemetry.lock_waits == 1
+        assert session.telemetry.lock_wait_s >= 0.04
+        (wait,) = _named(_spans(str(tmp_path)), "tuning.lock_wait")
+        assert wait["parents"] == ["tuning.submit"]
+        assert wait["end"] - wait["start"] >= 0.04e9
+
+        svc = TuningService(session)
+        try:
+            m = svc.metrics()
+        finally:
+            svc.shutdown(drain=False)
+        json.dumps(m)
+        assert m["lock_waits"] == 1
+        assert m["lock_wait_s"] == session.telemetry.lock_wait_s
+
+
+class TestTimedLock:
+    def test_reentrant_and_uncontended_acquires_count_nothing(self):
+        tel = Telemetry()
+        lock = TimedLock(tel)
+        with lock:
+            with lock:
+                assert lock.acquire(blocking=False)
+                lock.release()
+        assert tel.lock_waits == 0 and tel.lock_wait_s == 0.0
+
+    def test_non_blocking_try_on_a_held_lock_fails_uncounted(self):
+        tel = Telemetry()
+        lock = TimedLock(tel)
+        got = []
+        with lock:
+            t = threading.Thread(
+                target=lambda: got.append(lock.acquire(blocking=False)))
+            t.start()
+            t.join(10.0)
+        assert not t.is_alive()
+        assert got == [False]
+        assert tel.lock_waits == 0
+
+    def test_contended_waits_are_counted_without_lost_updates(self):
+        """Many threads on one lock: every failed non-blocking try becomes
+        exactly one counted wait, and the lock still excludes."""
+
+        class CountingRLock:
+            def __init__(self):
+                self._inner = threading.RLock()
+                self._guard = threading.Lock()
+                self.failed_tries = 0
+
+            def acquire(self, blocking=True, timeout=-1):
+                got = self._inner.acquire(blocking, timeout)
+                if not blocking and not got:
+                    with self._guard:
+                        self.failed_tries += 1
+                return got
+
+            def release(self):
+                self._inner.release()
+
+        tel = Telemetry()
+        lock = TimedLock(tel)
+        inner = lock._lock = CountingRLock()
+        shared = [0]
+        n_threads, n_iter = 16, 200
+
+        def work():
+            for _ in range(n_iter):
+                with lock:
+                    v = shared[0]
+                    time.sleep(0)
+                    shared[0] = v + 1
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=work)
+                       for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60.0)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert shared[0] == n_threads * n_iter
+        assert inner.failed_tries > 0
+        assert tel.lock_waits == inner.failed_tries
+        assert tel.lock_wait_s > 0.0
