@@ -19,6 +19,16 @@ w = warm-start seeds):
     feature (PR 3)   O(n·d)      O(B²d + B·n·d)+6·O(B²) 18·O(B³)        O(B·n)
     fused (PR 8)     O(n·d)      same flops, streamed   18·O(B³)        O(B·tile)
 
+The packed layouts' factorization extent depends on the backend the head
+is lowered for (`_packed_head`).  Elsewhere than on the TPU, XLA's
+Cholesky and `cho_solve` factor all B columns: 18·O(B³) per row, the
+column above.  On the TPU, where XLA's Cholesky and triangular inversion
+are loops over all B columns that cost time per column rather than per
+flop, a column loop runs over the t observed slots only: t trips of
+18·O(B²) elementwise work (under a chunk's vmap, the chunk's largest t),
+then one t-trip back-substitution for the selected grid point.  Columns
+≥ t are the identity either way.
+
 The fused row's last column is the *transient* bound: the EI/argmax tail
 runs as a streaming (max, argmax) reduction over n/tile tiles
 (`repro.kernels.ei_argmax`), so the (B,n) cross block — the feature
@@ -118,7 +128,11 @@ occupy slots < t like any observation (index in `tried`, float32 cost in
 padding proof applies verbatim to a seeded buffer — slots ≥ t stay inert,
 slots < t are ordinary training points.
 
-Float32 discipline (unchanged from the dense engine): XLA:CPU float32
+Float32 discipline (unchanged from the dense engine; it concerns the CPU,
+whose head is the full-extent LAPACK one, `_factor_lapack`, bit for bit
+as before — the TPU's column-loop head agrees with it to rounding, not
+bitwise, and computes every product elementwise in float32, never on the
+matrix unit's reduced-precision passes): XLA:CPU float32
 results differ between compilation contexts — batch extent 1 compiles to
 different programs than extents ≥ 2 (hence everything runs at extent ≥ 2),
 extents 2–8 are empirically invariant, ≥ 12 diverge, and `lax.while_loop`
@@ -283,6 +297,116 @@ def _masked_posterior(
     return lml, mean_n, var_n
 
 
+def _factor_lapack(ks18, nz18, pmask, y_train, t):
+    """XLA's factorization of the 18 masked grid matrices at full extent
+    B, whatever ``t``: `jnp.linalg.cholesky` and `cho_solve` per grid
+    point (LAPACK on the CPU).  Returns ``(lmls, best_h, chol, alpha)``:
+    the grid's log marginal likelihoods (-inf where not finite), the
+    index of the largest, and that grid point's factor and K⁻¹ y_train."""
+    b = y_train.shape[0]
+    pm = pmask.astype(jnp.float32)
+    diag_idx = jnp.arange(b)
+
+    def factorize(k_masked, noise):
+        """Masked-kernel Cholesky + lml for one (lengthscale, noise)."""
+        diag = jnp.where(pmask, noise + _JITTER, 1.0)
+        k_eff = k_masked.at[diag_idx, diag_idx].add(diag)
+        chol = jnp.linalg.cholesky(k_eff)
+        alpha = jax.scipy.linalg.cho_solve((chol, True), y_train)
+        lml = (
+            jnp.matmul(
+                -0.5 * y_train, alpha, precision=jax.lax.Precision.HIGHEST
+            )
+            - jnp.sum(jnp.log(jnp.diagonal(chol)) * pm)
+            - 0.5 * jnp.sum(pm) * jnp.log(2.0 * jnp.pi)
+        )
+        return lml, chol, alpha
+
+    lmls, chols, alphas = jax.vmap(factorize)(ks18, nz18)
+    lmls = jnp.where(jnp.isfinite(lmls), lmls, -jnp.inf)
+    best_h = jnp.argmax(lmls)
+    return lmls, best_h, chols[best_h], alphas[best_h]
+
+
+def _factor_loop(ks18, nz18, pmask, y_train, t):
+    """The same selection as `_factor_lapack`, with a column loop that
+    runs over the t observed slots only.
+
+    Right-looking Cholesky of all 18 grid matrices at once, in place: step
+    j writes column j of L over column j of the matrix and subtracts its
+    outer product from the trailing block; the same step carries the
+    forward substitution z = L⁻¹ y_train.  Padded columns (≥ t) are e_j in
+    the masked matrix, so they would come out as the identity; the loop
+    stops at t and leaves them so.  Each grid point's LML is then
+    −½ z·z − Σ log diag(L)·pm − ½ t log 2π, and the winner's alpha comes
+    from one back-substitution Lᵀα = z, again over t columns.  Columns are
+    picked by one-hot masks and every product is elementwise float32, so
+    nothing runs on the matrix unit's reduced-precision passes; a batch of
+    rows (vmap) runs to the largest t among them, the others idle.
+    """
+    b = y_train.shape[0]
+    pm = pmask.astype(jnp.float32)
+    idx = jnp.arange(b)
+    diag = jnp.where(pmask[None], nz18[:, None] + _JITTER, 1.0)  # (18, B)
+    a0 = jnp.where(idx[:, None] == idx[None, :], ks18 + diag[:, :, None], ks18)
+    z0 = jnp.broadcast_to(y_train, diag.shape)
+
+    # `lax.select` and 0/1 masks in the loop bodies, not `jnp.where`: each
+    # `jnp.where` is a jit of its own, which vmap batches anew in the trace
+    # of every chunk extent, and that trace is part of the service's set-up.
+    def column(j, carry):
+        a, z = carry  # (18, B, B) in-place factor, (18, B) L⁻¹ y so far
+        at = idx == j
+        e = at.astype(jnp.float32)
+        col = jnp.sum(a * e, axis=-1)  # (18, B) a[:, :, j]
+        d = jnp.sqrt(jnp.sum(col * e, axis=-1, keepdims=True))
+        l = col / d * (idx > j)  # L[j+1:, j]
+        zj = jnp.sum(z * e, axis=-1, keepdims=True) / d
+        new_col = at[None, None, :] & (idx >= j)[None, :, None]
+        a = jax.lax.select(
+            jnp.broadcast_to(new_col, a.shape),
+            jnp.broadcast_to((l + d * e)[:, :, None], a.shape),
+            a - l[:, :, None] * l[:, None, :],
+        )
+        z = jax.lax.select(jnp.broadcast_to(at, z.shape),
+                           jnp.broadcast_to(zj, z.shape), z - l * zj)
+        return a, z
+
+    a, z = jax.lax.fori_loop(0, t, column, (a0, z0))
+    lmls = (
+        -0.5 * jnp.sum(z * z, axis=-1)
+        - jnp.sum(jnp.log(jnp.diagonal(a, axis1=-2, axis2=-1)) * pm, axis=-1)
+        - 0.5 * jnp.sum(pm) * jnp.log(2.0 * jnp.pi)
+    )
+    lmls = jnp.where(jnp.isfinite(lmls), lmls, -jnp.inf)
+    best_h = jnp.argmax(lmls)
+    chol = jnp.where(idx[:, None] >= idx[None, :], a[best_h], 0.0)
+
+    def back(k, v):
+        # Lᵀα = z column by column, from j = t − 1 down; v holds z above
+        # the current column and α from it on.
+        j = t - 1 - k
+        at = idx == j
+        e = at.astype(jnp.float32)
+        row = jnp.sum(chol * e[:, None], axis=0)  # L[j, :]
+        aj = jnp.sum(v * e) / jnp.sum(row * e)
+        return jax.lax.select(at, jnp.broadcast_to(aj, v.shape),
+                              v - row * (idx < j) * aj)
+
+    alpha = jax.lax.fori_loop(0, t, back, z[best_h])
+    return lmls, best_h, chol, alpha
+
+
+def _factor(ks18, nz18, pmask, y_train, t):
+    """The head's factorization for the platform it is lowered for: the
+    trip-bounded loop on the TPU, where XLA's Cholesky and triangular
+    inversion are loops over all B columns; XLA's (LAPACK) elsewhere."""
+    return jax.lax.platform_dependent(
+        ks18, nz18, pmask, y_train, t,
+        tpu=_factor_loop, default=_factor_lapack,
+    )
+
+
 @jax.named_scope("gp_head")
 def _packed_head(
     d2_bb: jax.Array,  # (B, B) raw squared distances, training block
@@ -290,6 +414,7 @@ def _packed_head(
     t: jax.Array,  # () i32 observations made (valid packed slots)
     lengthscales: Tuple[float, ...] = _LENGTHSCALES,
     noises: Tuple[float, ...] = _NOISES,
+    factor=_factor,
 ) -> Tuple[jax.Array, ...]:
     """The training-side math every packed layout shares: target
     standardization, the 18-point (lengthscale, noise) grid, masked
@@ -298,6 +423,13 @@ def _packed_head(
     layout runs it verbatim and streams only the tail.  A narrower grid
     (``lengthscales`` × ``noises``) serves cross-backend checks that pin
     the selection.
+
+    The factorizations are `_factor`'s, chosen by the platform the head is
+    lowered for: on the TPU a column loop over the t observed slots
+    (`_factor_loop`), elsewhere XLA's full-extent Cholesky and `cho_solve`
+    (`_factor_lapack`, the CPU's golden bits).  Both return the same
+    identity-padded (B,B) factor, so the EI tail is the same either way.
+    ``factor`` takes one of them directly, to run the TPU head on the CPU.
 
     Returns ``(pm, best, ls_sel, chol, alpha, y_mean, y_std)``: the
     selected posterior factors the EI tail consumes.
@@ -326,37 +458,17 @@ def _packed_head(
 
     mm = pm[:, None] * pm[None, :]
     # Mask once per lengthscale (6 products), not per grid combo (18); the
-    # noise only touches the diagonal, added by a B-element scatter.
+    # noise only touches the diagonal, added per grid point.
     ks_masked = ks * mm[None]  # (6, B, B)
-    diag_idx = jnp.arange(b)
-
-    def factorize(k_masked, noise):
-        """Masked-kernel Cholesky + lml for one (lengthscale, noise)."""
-        diag = jnp.where(pmask, noise + _JITTER, 1.0)
-        k_eff = k_masked.at[diag_idx, diag_idx].add(diag)
-        chol = jnp.linalg.cholesky(k_eff)
-        alpha = jax.scipy.linalg.cho_solve((chol, True), y_train)
-        lml = (
-            jnp.matmul(
-                -0.5 * y_train, alpha, precision=jax.lax.Precision.HIGHEST
-            )
-            - jnp.sum(jnp.log(jnp.diagonal(chol)) * pm)
-            - 0.5 * jnp.sum(pm) * jnp.log(2.0 * jnp.pi)
-        )
-        return lml, chol, alpha
-
     # ls-major grid order (matches jnp.meshgrid(..., indexing="ij")):
     # combo h = (h // 3)-th lengthscale, (h % 3)-th noise.
     ks18 = jnp.repeat(ks_masked, nz.shape[0], axis=0)  # (18, B, B)
     nz18 = jnp.tile(nz, ls.shape[0])  # (18,)
-    lmls, chols, alphas = jax.vmap(factorize)(ks18, nz18)
-    lmls = jnp.where(jnp.isfinite(lmls), lmls, -jnp.inf)
-    best_h = jnp.argmax(lmls)
+    _, best_h, chol, alpha = factor(ks18, nz18, pmask, y_train, t)
 
     best = jnp.min(jnp.where(pmask, py, jnp.inf))
     return (
-        pm, best, ls[best_h // nz.shape[0]], chols[best_h], alphas[best_h],
-        y_mean, y_std,
+        pm, best, ls[best_h // nz.shape[0]], chol, alpha, y_mean, y_std,
     )
 
 

@@ -102,7 +102,9 @@ from repro.fleet.sharding import (
     resolve_shard_devices,
     sharded_update,
 )
-from repro.fleet.telemetry import Telemetry, TimedLock, recording, span
+from repro.fleet.telemetry import (
+    Telemetry, TimedLock, head_slots, recording, span,
+)
 
 if TYPE_CHECKING:  # import cycle: driver imports session for tune_fleet
     from repro.fleet.driver import FleetJob
@@ -578,13 +580,16 @@ class _LiveChunk:
     ``group_key`` is the admission-group identity ((space shape, packed
     capacity)) — the unit the async service schedules: every chunk of one
     key is stepped by the same group thread (`repro.fleet.service`).
+    ``t_admit`` is each row's observation count at admission, which with
+    the count at retirement gives the group's `head_slots` counter.
     """
 
     __slots__ = ("state", "args", "members", "capacity", "update",
-                 "steps_done", "steps_needed", "n_shards", "group_key")
+                 "steps_done", "steps_needed", "n_shards", "group_key",
+                 "t_admit")
 
     def __init__(self, state, args, members, capacity, update,
-                 steps_needed, n_shards=1, group_key=None):
+                 steps_needed, n_shards=1, group_key=None, t_admit=None):
         self.state = state
         self.args = args
         self.members = members
@@ -594,6 +599,7 @@ class _LiveChunk:
         self.steps_needed = steps_needed
         self.n_shards = n_shards
         self.group_key = group_key
+        self.t_admit = t_admit  # (n_shards, rows) host copy of state.t
 
 
 class _SpaceEntry:
@@ -1560,6 +1566,7 @@ class TuningSession:
                     steps_needed=max(p[2] for p in parts),
                     n_shards=n_shards,
                     group_key=(shape, cap),
+                    t_admit=np.stack([p[0].t for p in parts]),
                 )
             )
         return out
@@ -1605,6 +1612,7 @@ class TuningSession:
             update=lambda st, a: _fleet_update(st, *a, xi=xi, layout=layout),
             steps_needed=steps_needed,
             group_key=(shape, cap),
+            t_admit=state_np.t[None],
         )
 
     def _chunk_arrays(
@@ -1710,6 +1718,13 @@ class TuningSession:
         s_t = np.asarray(ch.state.t).reshape(-1)
         s_stop = np.asarray(ch.state.stop).reshape(-1)
         s_pb = np.asarray(ch.state.pb).reshape(-1)
+        counters = self.telemetry.group(ch.group_key)
+        counters.head_slots += head_slots(
+            ch.t_admit, s_t.reshape(ch.t_admit.shape), ch.steps_done
+        )
+        counters.head_capacity_slots += (
+            ch.t_admit.shape[0] * ch.steps_done * cap
+        )
         for i, rec in enumerate(ch.members):
             if rec is None:
                 continue  # retired mid-flight; outcome already published

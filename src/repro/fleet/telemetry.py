@@ -21,7 +21,8 @@ arguments are attached only while a trace records.
 Counters are always on and are timed with `time.perf_counter` at the
 boundaries of the matching span: per admission group (`GroupCounters`)
 and session-wide (`Telemetry`, the lock waits).  `TuningService.metrics()`
-reports them.
+reports them.  Two of a group's counters are slot counts, not times: the
+GP head's column-loop trips on the TPU and its capacity (`head_slots`).
 """
 
 from __future__ import annotations
@@ -30,9 +31,11 @@ import threading
 import time
 from typing import Dict
 
+import numpy as np
 from jax.profiler import TraceAnnotation
 
-__all__ = ["GroupCounters", "Telemetry", "TimedLock", "recording", "span"]
+__all__ = ["GroupCounters", "Telemetry", "TimedLock", "head_slots",
+           "recording", "span"]
 
 recording = TraceAnnotation.is_enabled
 
@@ -51,7 +54,8 @@ class GroupCounters:
     the fields are plain attributes."""
 
     __slots__ = ("dispatches", "polls", "admissions", "empty_admissions",
-                 "admit_s", "dispatch_s", "poll_wait_s", "retire_s")
+                 "admit_s", "dispatch_s", "poll_wait_s", "retire_s",
+                 "head_slots", "head_capacity_slots")
 
     def __init__(self) -> None:
         self.dispatches = 0  # update enqueues (`tuning.dispatch`)
@@ -62,9 +66,30 @@ class GroupCounters:
         self.dispatch_s = 0.0
         self.poll_wait_s = 0.0
         self.retire_s = 0.0
+        # Over retired chunks: the GP head's trip bound summed over each
+        # chunk's dispatches (`head_slots`), and its capacity B summed
+        # over the same dispatches.
+        self.head_slots = 0
+        self.head_capacity_slots = 0
 
     def as_dict(self) -> dict:
         return {f: getattr(self, f) for f in self.__slots__}
+
+
+def head_slots(t_admit, t_retired, dispatches: int) -> int:
+    """The GP head's trip bound summed over a chunk's ``dispatches``.
+
+    On the TPU the head's column loop runs to the largest observation
+    count t among the rows it advances together (`fast_bo._factor_loop`
+    under the chunk's vmap).  A row's t grows by one per dispatch until
+    its search stops, and never after, so at dispatch s it is
+    min(t_admit + s, t_retired): the bound follows from each row's t at
+    admission and at retirement, with no device sync of its own.
+    ``t_admit`` and ``t_retired`` are (chunks, rows), one chunk per shard.
+    """
+    s = np.arange(dispatches)[:, None, None]
+    per_step = np.minimum(np.asarray(t_admit) + s, np.asarray(t_retired))
+    return int(per_step.max(axis=-1).sum())
 
 
 class Telemetry:

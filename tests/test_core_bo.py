@@ -314,6 +314,119 @@ class TestPackedEngine:
         _assert_pick_near_optimal(ei_ref, int(pick_d))
 
 
+# `bench/reference.py`'s tie: grid points whose log marginal likelihoods
+# lie within this many nats may be picked either way.
+LML_TIE = 1e-4
+
+
+@jax.jit
+def _both_heads(d2_bb, py, t):
+    """The head through XLA's factorization and through the TPU's column
+    loop, with each factorization's own outputs (the LML grid among them)."""
+    got = {}
+
+    def keep(name, factor):
+        def run(*args):
+            got[name] = factor(*args)
+            return got[name]
+
+        return run
+
+    got["lapack_head"] = fast_bo._packed_head(
+        d2_bb, py, t, factor=keep("lapack", fast_bo._factor_lapack)
+    )
+    got["loop_head"] = fast_bo._packed_head(
+        d2_bb, py, t, factor=keep("loop", fast_bo._factor_loop)
+    )
+    return got
+
+
+def _head_case(seed, b, t):
+    rng = np.random.default_rng(seed)
+    feats = rng.normal(size=(b, 4)).astype(np.float32)
+    py = (np.sum(feats**2, -1) + 0.3 * rng.normal(size=b)).astype(np.float32)
+    return pairwise_sqdist(jnp.asarray(feats)), jnp.asarray(py)
+
+
+def _assert_loop_matches_lapack(got, t):
+    b = got["loop_head"][3].shape[-1]
+    lml_lapack, h_lapack = np.asarray(got["lapack"][0]), int(got["lapack"][1])
+    lml_loop, h_loop = np.asarray(got["loop"][0]), int(got["loop"][1])
+    np.testing.assert_allclose(lml_loop, lml_lapack, rtol=1e-3, atol=1e-3)
+    # The same grid point, unless the two are a near-tie.
+    assert h_loop == h_lapack or (
+        lml_lapack[h_lapack] - lml_lapack[h_loop] <= LML_TIE
+    )
+    if h_loop != h_lapack:
+        return
+    chol_lapack, alpha_lapack = got["lapack_head"][3:5]
+    chol, alpha = got["loop_head"][3:5]
+    np.testing.assert_allclose(chol, chol_lapack, rtol=0, atol=1e-5)
+    scale = 1.0 + float(jnp.max(jnp.abs(alpha_lapack)))
+    np.testing.assert_allclose(alpha, alpha_lapack, rtol=0, atol=1e-4 * scale)
+    # Columns past the observed slots are exactly the identity's.
+    np.testing.assert_array_equal(np.asarray(chol)[:, t:], np.eye(b)[:, t:])
+    # pm, best, the selected lengthscale, y_mean and y_std: bit for bit.
+    for k in (0, 1, 2, 5, 6):
+        np.testing.assert_array_equal(got["loop_head"][k],
+                                      got["lapack_head"][k])
+
+
+class TestLoopHead:
+    """The TPU's GP head (`fast_bo._factor_loop`: a column loop over the t
+    observed slots) run on the CPU against XLA's full-extent factorization
+    (`_factor_lapack`, the CPU's head)."""
+
+    @pytest.mark.parametrize("b", [8, 24, 69])
+    @pytest.mark.parametrize("fill", ["none", "random", "full"])
+    def test_matches_lapack_head(self, b, fill):
+        for seed in range(4):
+            rng = np.random.default_rng(1000 * b + seed)
+            t = {"none": 0, "full": b}.get(fill, int(rng.integers(1, b)))
+            d2, py = _head_case(seed, b, t)
+            got = _both_heads(d2, py, jnp.asarray(t, jnp.int32))
+            _assert_loop_matches_lapack(got, t)
+
+    @pytest.mark.parametrize("b", [8, 24, 69])
+    def test_batch_of_rows_with_different_t(self, b):
+        """Under the chunk's vmap the loop runs to the largest t of the
+        batch; every row still matches its own full-extent factorization."""
+        rows = 6
+        ts = np.random.default_rng(b).integers(0, b + 1, size=rows)
+        ts[:2] = (0, b)
+        cases = [_head_case(100 + i, b, int(ts[i])) for i in range(rows)]
+        d2 = jnp.stack([c[0] for c in cases])
+        py = jnp.stack([c[1] for c in cases])
+        got = jax.vmap(_both_heads)(d2, py, jnp.asarray(ts, jnp.int32))
+        for i, t in enumerate(ts):
+            row = jax.tree_util.tree_map(lambda x, i=i: x[i], got)
+            _assert_loop_matches_lapack(row, int(t))
+
+    @pytest.mark.parametrize("b", [8, 24, 69])
+    def test_padded_slots_are_bitwise_inert(self, b):
+        """Finite garbage in the distances and costs of slots ≥ t changes
+        no bit of the loop head's outputs."""
+        loop_head = jax.jit(
+            lambda d2, py, t: fast_bo._packed_head(
+                d2, py, t, factor=fast_bo._factor_loop
+            )
+        )
+        rng = np.random.default_rng(7 * b)
+        for t in (0, 1, b // 2, b - 1):
+            d2, py = _head_case(b + t, b, t)
+            d2_g = np.array(d2)
+            py_g = np.array(py)
+            junk = np.abs(1e3 * rng.standard_normal((b, b))).astype(np.float32)
+            d2_g[t:, :] = junk[t:, :]
+            d2_g[:, t:] = junk[:, t:]
+            py_g[t:] = 1e6 * rng.standard_normal(b - t)
+            tt = jnp.asarray(t, jnp.int32)
+            ref = loop_head(d2, py, tt)
+            got = loop_head(jnp.asarray(d2_g), jnp.asarray(py_g), tt)
+            for r, g in zip(ref, got):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
 class TestSqdistKernelHelpers:
     def test_matern_from_sqdist_matches_matern52_scalar_ls(self):
         """One raw d² rescaled per lengthscale must reproduce matern52 for

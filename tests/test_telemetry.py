@@ -15,6 +15,7 @@ import threading
 import time
 
 import jax
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
@@ -169,6 +170,36 @@ class TestSpans:
         json.dumps(m)
         assert m["lock_waits"] == 1
         assert m["lock_wait_s"] == session.telemetry.lock_wait_s
+
+
+class TestHeadSlots:
+    @pytest.mark.parametrize("cancel", [False, True])
+    def test_static_chunk_counts_its_largest_t_per_dispatch(self, cancel):
+        """``head_slots`` is the GP head's trip bound (the chunk's largest
+        t) summed over the chunk's dispatches, read before each one here;
+        ``head_capacity_slots`` is B per dispatch.  A member cancelled
+        mid-flight keeps its frozen t in the chunk."""
+        session = TuningSession(**_session_kwargs())
+        space, table = synth_space_table(24)
+        handles = [
+            session.submit(FleetJob(name=f"j{s}", space=space,
+                                    cost_table=table),
+                           seed=s, mode="cherrypick")
+            for s in range(5)
+        ]
+        session._admit()
+        (ch,) = session._chunks
+        bounds = []
+        while session._chunks:
+            bounds.append(int(np.asarray(ch.state.t).max()))
+            session.step()
+            if cancel and len(bounds) == 4:
+                assert session.cancel(handles[0])
+        (g,) = session.telemetry.groups().values()
+        assert g["dispatches"] == len(bounds)
+        assert g["head_slots"] == sum(bounds)
+        assert g["head_capacity_slots"] == len(bounds) * ch.capacity
+        assert 0 < g["head_slots"] < g["head_capacity_slots"]
 
 
 class TestTimedLock:

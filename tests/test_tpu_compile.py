@@ -11,6 +11,8 @@ compiles (a TPU executable written there cannot be read back without a
 chip).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -115,9 +117,49 @@ def test_catalog_chunk_update_compiles(one_chip, kernel_lane, layout):
     assert ("tpu_custom_call" in compiled.as_text()) == (layout == "fused")
 
 
-def test_paper_chunk_update_compiles(one_chip):
-    compiled = compile_chunk_update(one_chip, "feature", **PAPER)
-    assert compiled.memory_analysis() is not None
+@pytest.fixture(scope="module")
+def paper_update(one_chip):
+    return compile_chunk_update(one_chip, "feature", **PAPER)
+
+
+def test_paper_chunk_update_compiles(paper_update):
+    assert paper_update.memory_analysis() is not None
+
+
+def _custom_calls(text):
+    return re.findall(r'custom_call_target="([^"]+)"[^\n]*?op_name="([^"]*)"',
+                      text)
+
+
+def test_paper_gp_head_factorizes_in_a_loop(paper_update):
+    """On the TPU the GP head is a column loop over the observed slots:
+    neither XLA's Cholesky nor `cho_solve`'s triangular inversion is left
+    in it.  The EI tail's own triangular solve stays."""
+    calls = _custom_calls(paper_update.as_text())
+    assert not [c for c in calls if c[0] == "Cholesky"]
+    inversions = [op for target, op in calls
+                  if target == "InvertDiagBlocksLowerTriangular"]
+    assert len(inversions) == 1 and "gp_head" not in inversions[0]
+
+
+def test_cpu_gp_head_keeps_lapack():
+    """Lowered for the CPU, the same update keeps XLA's (LAPACK) Cholesky
+    and `cho_solve`, so the CPU's numbers are those of the full-extent
+    factorization."""
+    cpu = SingleDeviceSharding(jax.devices("cpu")[0])
+    state, args = chunk_args(cpu, (ROWS,), **PAPER)
+    tail = tuple(
+        jax.ShapeDtypeStruct((), dt, sharding=cpu)
+        for dt in (jnp.int32, jnp.float32, bool)
+    )
+    text = _fleet_update.lower(
+        state, *args, *tail, xi=0.0, layout="feature"
+    ).compile().as_text()
+    calls = _custom_calls(text)
+    assert [op for target, op in calls
+            if target.startswith("lapack_spotrf") and "gp_head" in op]
+    assert [op for target, op in calls
+            if target.startswith("lapack_strsm") and "gp_head" in op]
 
 
 def test_sharded_chunk_update_compiles(topo, kernel_lane):
