@@ -33,6 +33,10 @@ from repro.cluster.workloads import (
     pricing_scenarios,
     spot_volatility_scenarios,
 )
+from repro.cluster.catalog import (
+    enumerate_catalog,
+    make_catalog_space,
+)
 from repro.cluster.simulator import (
     ClusterSimulator,
     job_cost_table,
@@ -54,12 +58,14 @@ __all__ = [
     "SpotSchedule",
     "default_catalogs",
     "drift_spec",
+    "enumerate_catalog",
     "enumerate_cluster_configs",
     "failure_scenario_jobs",
     "family_constrained_scenarios",
     "family_indices",
     "job_cost_table",
     "job_runtime_table",
+    "make_catalog_space",
     "make_cluster_search_space",
     "make_profile_run_fn",
     "pricing_scenarios",

@@ -40,6 +40,12 @@ class NodeType:
     cores: int
     memory_gb: float
     price_per_hour: float  # USD, on-demand
+    processor: str = "intel"  # "intel" | "amd" | "graviton"
+    generation: int = 4
+    # Multiplies the emulated runtime (`simulator.runtime_hours`): 1.0 for
+    # the paper's c4/m4/r4 grid; catalog node types carry their
+    # processor's and generation's offsets (`repro.cluster.catalog`).
+    runtime_factor: float = 1.0
 
 
 NODE_TYPES: Dict[str, NodeType] = {

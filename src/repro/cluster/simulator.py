@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -104,15 +104,20 @@ def runtime_hours(job: JobSpec, cfg: ClusterConfig) -> float:
     )
     coord = 1.0 + job.coord_per_node * (cfg.scale_out - 1)
     rug = np.exp(job.rugged_sigma * _hash_unit_normal(job.key, cfg.name))
-    return base * coord * _spill_factor(job, cfg) * rug
+    return (base * coord * _spill_factor(job, cfg) * rug
+            * cfg.node.runtime_factor)
 
 
 def job_runtime_table(
-    job: JobSpec, catalog: Optional[PriceCatalog] = None
+    job: JobSpec,
+    catalog: Optional[PriceCatalog] = None,
+    configs: Optional[Sequence[ClusterConfig]] = None,
 ) -> np.ndarray:
-    """(69,) hours per configuration.  ``catalog`` applies its arch's
-    runtime offset (`PriceCatalog.perf_factor`); None is the x86 baseline."""
-    configs = enumerate_cluster_configs()
+    """(n,) hours per configuration of ``configs`` (default: the paper's
+    69-config grid).  ``catalog`` applies its arch's runtime offset
+    (`PriceCatalog.perf_factor`); None is the x86 baseline."""
+    if configs is None:
+        configs = enumerate_cluster_configs()
     rt = np.asarray([runtime_hours(job, c) for c in configs], np.float64)
     if catalog is not None and catalog.perf_factor != 1.0:
         rt = rt * catalog.perf_factor
@@ -120,23 +125,28 @@ def job_runtime_table(
 
 
 def job_cost_table(
-    job: JobSpec, catalog: Optional[PriceCatalog] = None, epoch: int = 0
+    job: JobSpec,
+    catalog: Optional[PriceCatalog] = None,
+    epoch: int = 0,
+    configs: Optional[Sequence[ClusterConfig]] = None,
 ) -> np.ndarray:
-    """(69,) USD execution cost per configuration, deterministic.
+    """(n,) USD execution cost per configuration of ``configs`` (default:
+    the paper's 69-config grid), deterministic.
 
-    With ``catalog=None`` (default) this is the legacy book — the
-    committed x86 on-demand prices, bit-identical to every pinned trace.
-    A catalog reprices the same configurations (runtime×price under its
-    book at ``epoch``); the identity catalog (`pricing.on_demand()`)
-    reproduces the legacy values bit-for-bit.
+    With ``catalog=None`` (default) this is each node type's own
+    on-demand price — on the paper grid the legacy book, bit-identical to
+    every pinned trace.  A catalog reprices the same configurations
+    (runtime×price under its book at ``epoch``); the identity catalog
+    (`pricing.on_demand()`) reproduces the legacy values bit-for-bit.
     """
-    configs = enumerate_cluster_configs()
+    if configs is None:
+        configs = enumerate_cluster_configs()
     if catalog is None:
         return np.asarray(
             [runtime_hours(job, c) * c.price_per_hour for c in configs],
             np.float64,
         )
-    return job_runtime_table(job, catalog) * catalog.price_table(
+    return job_runtime_table(job, catalog, configs) * catalog.price_table(
         configs, epoch=epoch
     )
 

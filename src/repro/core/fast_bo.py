@@ -198,6 +198,12 @@ _NOISES = (1e-4, 1e-2, 1e-1)
 
 _LAYOUTS = ("feature", "gather", "fused")
 
+# The name scope of the extent-n EI tail in the feature and fused layouts:
+# the cross distance block, posterior, EI and argmax over the candidates
+# (in `fused`, the `ei_argmax` kernel).  Metadata only: a profile
+# attributes device time by it, the numerics do not change.
+EI_TAIL_SCOPE = "ei_tail"
+
 
 def encode_features(encoded) -> np.ndarray:
     """Canonical float32 host view of the encoded space.
@@ -491,12 +497,14 @@ def _packed_core(
     pm, best, ls_sel, chol, alpha, y_mean, y_std = _packed_head(d2_bb, py, t)
     # Posterior + EI over all n points for the selected hyperparameters
     # only: one (B,n) rescale of the cross block, masked training rows.
-    ei = ei_from_sqdist(
-        d2_bn, pm[:, None], alpha, chol, ls_sel, y_mean, y_std, best,
-        cand_mask & ~obs_mask, xi,
-    )
-    pick = jnp.argmax(ei)
-    return pick, jnp.max(ei), best
+    with jax.named_scope(EI_TAIL_SCOPE):
+        ei = ei_from_sqdist(
+            d2_bn, pm[:, None], alpha, chol, ls_sel, y_mean, y_std, best,
+            cand_mask & ~obs_mask, xi,
+        )
+        pick = jnp.argmax(ei)
+        max_ei = jnp.max(ei)
+    return pick, max_ei, best
 
 
 def bo_step_core(
@@ -515,8 +523,11 @@ def bo_step_core(
     All training-side linear algebra runs at the packed capacity B; the
     space extent n only appears in the O(Bnd) cross-block matmul, the (B,n)
     rescale, and the EI argmax.  Nothing of extent n² exists anywhere.
+    The extent-n work (the cross block, then `_packed_core`'s tail) runs
+    under the ``ei_tail`` name scope.
     """
-    d2_bb, d2_bn = packed_sqdist_blocks(feats, encoded, tried)
+    with jax.named_scope(EI_TAIL_SCOPE):
+        d2_bb, d2_bn = packed_sqdist_blocks(feats, encoded, tried)
     return _packed_core(d2_bb, d2_bn, py, t, obs_mask, cand_mask, xi)
 
 
@@ -567,10 +578,12 @@ def bo_step_core_fused(
     idx = jnp.maximum(tried, 0)  # padded slots: column 0, masked via pm
     d2_bb = pairwise_sqdist(feats, encoded[idx])
     pm, best, ls_sel, chol, alpha, y_mean, y_std = _packed_head(d2_bb, py, t)
-    pick, max_ei = ei_argmax(
-        encoded, cand_mask & ~obs_mask, feats, pm, alpha, chol,
-        ls_sel, y_mean, y_std, best, xi=xi, tile=tile, interpret=interpret,
-    )
+    with jax.named_scope(EI_TAIL_SCOPE):
+        pick, max_ei = ei_argmax(
+            encoded, cand_mask & ~obs_mask, feats, pm, alpha, chol,
+            ls_sel, y_mean, y_std, best, xi=xi, tile=tile,
+            interpret=interpret,
+        )
     return pick, max_ei, best
 
 

@@ -103,7 +103,7 @@ from repro.fleet.sharding import (
     sharded_update,
 )
 from repro.fleet.telemetry import (
-    Telemetry, TimedLock, head_slots, recording, span,
+    Telemetry, TimedLock, ei_work, head_slots, recording, span,
 )
 
 if TYPE_CHECKING:  # import cycle: driver imports session for tune_fleet
@@ -582,14 +582,18 @@ class _LiveChunk:
     key is stepped by the same group thread (`repro.fleet.service`).
     ``t_admit`` is each row's observation count at admission, which with
     the count at retirement gives the group's `head_slots` counter.
+    ``budget`` is each row's trial budget and ``polls`` the done flags
+    read at each poll, as (steps done, flat flags): with ``t_admit`` they
+    give the EI tail's rows and slots per dispatch (`telemetry.ei_work`).
     """
 
     __slots__ = ("state", "args", "members", "capacity", "update",
                  "steps_done", "steps_needed", "n_shards", "group_key",
-                 "t_admit")
+                 "t_admit", "budget", "polls")
 
     def __init__(self, state, args, members, capacity, update,
-                 steps_needed, n_shards=1, group_key=None, t_admit=None):
+                 steps_needed, n_shards=1, group_key=None, t_admit=None,
+                 budget=None):
         self.state = state
         self.args = args
         self.members = members
@@ -600,6 +604,14 @@ class _LiveChunk:
         self.n_shards = n_shards
         self.group_key = group_key
         self.t_admit = t_admit  # (n_shards, rows) host copy of state.t
+        self.budget = budget  # (n_shards, rows) host copy of max_trials
+        self.polls: List[Tuple[int, np.ndarray]] = []
+
+    def ei_work(self, steps) -> Tuple[np.ndarray, np.ndarray]:
+        """(rows, slots) of the EI tail at each dispatch index of
+        ``steps`` (see `telemetry.ei_work`)."""
+        return ei_work(self.t_admit, self.budget, len(self.members),
+                       self.polls, steps)
 
 
 class _SpaceEntry:
@@ -906,17 +918,18 @@ class TuningSession:
             )
             # §III-D narrowing, vectorized over the static per-config
             # arrays; remaining is always the complement.
-            prio_mask = split_priority_mask(
-                space,
-                profile.model,
-                job.full_input_size,
-                per_node_overhead=job.per_node_overhead,
-                leeway=job.leeway,
-                flat_fraction=job.flat_fraction,
-            )
-            rem_mask = ~prio_mask
-            prio_idx = np.flatnonzero(prio_mask)
-            rem_idx = np.flatnonzero(rem_mask)
+            with span("tuning.split"):
+                prio_mask = split_priority_mask(
+                    space,
+                    profile.model,
+                    job.full_input_size,
+                    per_node_overhead=job.per_node_overhead,
+                    leeway=job.leeway,
+                    flat_fraction=job.flat_fraction,
+                )
+                rem_mask = ~prio_mask
+                prio_idx = np.flatnonzero(prio_mask)
+                rem_idx = np.flatnonzero(rem_mask)
 
         budget = trial_budget(len(prio_idx), len(rem_idx), self.settings)
 
@@ -1099,7 +1112,9 @@ class TuningSession:
         Three spans, each with its counter: ``tuning.dispatch`` (the
         enqueue of the update), ``tuning.poll`` (the done-flag sync) and
         ``tuning.retire`` (the pre-retirement sync, `_retire` and the
-        publishes)."""
+        publishes).  While a trace records, ``tuning.dispatch`` carries
+        the EI tail's ``rows`` and ``slots`` for this update (`ei_work`);
+        the poll keeps the flags it read for them."""
         counters = self.telemetry.group(ch.group_key)
         with self._lock:
             if ch not in self._chunks:
@@ -1108,7 +1123,10 @@ class TuningSession:
                 self._chunks.remove(ch)
                 return "dead"
             t0 = time.perf_counter()
-            with span("tuning.dispatch"):
+            with span("tuning.dispatch") as sp:
+                if recording():
+                    rows, slots = ch.ei_work([ch.steps_done])
+                    sp.set_metadata(rows=int(rows[0]), slots=int(slots[0]))
                 ch.state = ch.update(ch.state, ch.args)
             counters.dispatches += 1
             counters.dispatch_s += time.perf_counter() - t0
@@ -1124,9 +1142,11 @@ class TuningSession:
             # Blocks on this chunk's device queue only.
             t0 = time.perf_counter()
             with span("tuning.poll"):
-                retire = bool(jnp.all(done_flags))
+                flags = np.asarray(done_flags).reshape(-1)
             counters.polls += 1
             counters.poll_wait_s += time.perf_counter() - t0
+            retire = bool(flags.all())
+            ch.polls.append((ch.steps_done, flags))
         if not retire:
             return "stepped"
         t0 = time.perf_counter()
@@ -1567,6 +1587,7 @@ class TuningSession:
                     n_shards=n_shards,
                     group_key=(shape, cap),
                     t_admit=np.stack([p[0].t for p in parts]),
+                    budget=np.stack([p[1][6] for p in parts]),
                 )
             )
         return out
@@ -1613,6 +1634,7 @@ class TuningSession:
             steps_needed=steps_needed,
             group_key=(shape, cap),
             t_admit=state_np.t[None],
+            budget=args_np[6][None],
         )
 
     def _chunk_arrays(
@@ -1725,6 +1747,9 @@ class TuningSession:
         counters.head_capacity_slots += (
             ch.t_admit.shape[0] * ch.steps_done * cap
         )
+        rows, slots = ch.ei_work(np.arange(ch.steps_done))
+        counters.ei_rows += int(rows.sum())
+        counters.ei_slots += int(slots.sum())
         for i, rec in enumerate(ch.members):
             if rec is None:
                 continue  # retired mid-flight; outcome already published

@@ -9,10 +9,12 @@ arguments are attached only while a trace records.
 
     span                 where (repro.fleet)          covers
     tuning.submit        TuningSession.submit         profile, split, enqueue
+    tuning.split         TuningSession._submit_locked the §III-D split
     tuning.admit         TuningSession._admit_group   a non-empty admission
     tuning.chunk_arrays  _build_chunk/_build_sharded  host state and args
     tuning.device_put    _build_chunk/_build_sharded  their transfers
     tuning.dispatch      TuningSession._step_chunk    enqueue of one update
+                                                      (rows, slots: `ei_work`)
     tuning.poll          TuningSession._step_chunk    the done-flag sync
     tuning.retire        TuningSession._step_chunk    sync, retire, publish
     tuning.lock_wait     the session lock             a contended acquire
@@ -21,8 +23,9 @@ arguments are attached only while a trace records.
 Counters are always on and are timed with `time.perf_counter` at the
 boundaries of the matching span: per admission group (`GroupCounters`)
 and session-wide (`Telemetry`, the lock waits).  `TuningService.metrics()`
-reports them.  Two of a group's counters are slot counts, not times: the
-GP head's column-loop trips on the TPU and its capacity (`head_slots`).
+reports them.  Four of a group's counters are counts, not times: the
+GP head's column-loop trips on the TPU and its capacity (`head_slots`),
+and the rows and observed slots the EI tail worked on (`ei_work`).
 """
 
 from __future__ import annotations
@@ -34,8 +37,8 @@ from typing import Dict
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-__all__ = ["GroupCounters", "Telemetry", "TimedLock", "head_slots",
-           "recording", "span"]
+__all__ = ["GroupCounters", "Telemetry", "TimedLock", "ei_work",
+           "head_slots", "recording", "span"]
 
 recording = TraceAnnotation.is_enabled
 
@@ -55,7 +58,7 @@ class GroupCounters:
 
     __slots__ = ("dispatches", "polls", "admissions", "empty_admissions",
                  "admit_s", "dispatch_s", "poll_wait_s", "retire_s",
-                 "head_slots", "head_capacity_slots")
+                 "head_slots", "head_capacity_slots", "ei_rows", "ei_slots")
 
     def __init__(self) -> None:
         self.dispatches = 0  # update enqueues (`tuning.dispatch`)
@@ -71,6 +74,11 @@ class GroupCounters:
         # over the same dispatches.
         self.head_slots = 0
         self.head_capacity_slots = 0
+        # Over retired chunks: the EI tail's rows and slots (`ei_work`)
+        # summed over each chunk's dispatches, as the dispatch spans
+        # give them one by one.
+        self.ei_rows = 0
+        self.ei_slots = 0
 
     def as_dict(self) -> dict:
         return {f: getattr(self, f) for f in self.__slots__}
@@ -90,6 +98,29 @@ def head_slots(t_admit, t_retired, dispatches: int) -> int:
     s = np.arange(dispatches)[:, None, None]
     per_step = np.minimum(np.asarray(t_admit) + s, np.asarray(t_retired))
     return int(per_step.max(axis=-1).sum())
+
+
+def ei_work(t_admit, budget, members: int, polls, steps):
+    """(rows, slots) of the EI tail at each of a chunk's dispatch indices
+    ``steps``: ``rows`` counts its first ``members`` rows (dummy pads
+    trail them) not known done at the last poll before the dispatch, and
+    ``slots`` sums those rows' observation counts t.
+
+    A row's t at dispatch s is at most min(t_admit + s, budget): it grows
+    by one per dispatch until the search stops, which the host learns
+    only at a poll.  ``t_admit`` and ``budget`` are (chunks, rows), one
+    chunk per shard, flattened in member order; ``polls`` is
+    [(dispatches done, flat done flags)] in order.  Returns two arrays
+    over ``steps``.
+    """
+    s = np.asarray(steps, np.int64)[:, None]
+    t0 = np.asarray(t_admit).reshape(-1)[:members]
+    cap = np.asarray(budget).reshape(-1)[:members]
+    searching = np.ones((s.shape[0], members), bool)
+    for at, flags in polls:
+        searching[s[:, 0] >= at] = ~flags[:members]
+    t = np.minimum(t0 + s, cap)
+    return searching.sum(axis=1), np.where(searching, t, 0).sum(axis=1)
 
 
 class Telemetry:

@@ -166,6 +166,9 @@ def ei_argmax_kernel_call(
 ):
     """((1, 1) f32 max EI, (1, 1) i32 argmax) over the masked candidates.
 
+    The call is named ``ei_argmax``: the TPU custom call, and so its ops in
+    a profile, carry the name.
+
     Scalars live in 2-D (1, k) SMEM arrays: under the engines' chunk
     `vmap` the batch axis becomes a squeezed leading block dimension, and
     Mosaic requires the trailing two block dimensions to span the array."""
@@ -214,5 +217,6 @@ def ei_argmax_kernel_call(
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="ei_argmax",
         **kwargs,
     )(enc, feats, pm[:, None], alpha, chol, scal[None, :], mask)

@@ -25,6 +25,7 @@ from repro.kernels.ei_argmax import ops as ei_ops
 from repro.kernels.ei_argmax.kernel import ei_argmax_kernel_call
 
 CATALOG = dict(n=32768, d=6, b=24)  # the catalog-scale fleet
+EC2 = dict(n=129024, d=6, b=24)  # the gen-6/7 c/m/r EC2 catalog
 PAPER = dict(n=69, d=4, b=69)  # Table I jobs over the 69-config grid
 ROWS = 8  # lockstep chunk extent
 N_INIT = 3  # scripted random-init slots
@@ -115,6 +116,18 @@ def test_ei_argmax_kernel_compiles(one_chip):
 def test_catalog_chunk_update_compiles(one_chip, kernel_lane, layout):
     compiled = compile_chunk_update(one_chip, layout, **CATALOG)
     assert ("tpu_custom_call" in compiled.as_text()) == (layout == "fused")
+
+
+def test_ec2_catalog_fused_update_compiles(one_chip, kernel_lane):
+    """The fused chunk update over the whole EC2 catalog (126 instance
+    types × 1–1024 nodes) at B = 24, 8 rows: the kernel is there, under
+    its own name and inside the ``ei_tail`` scope."""
+    text = compile_chunk_update(one_chip, "fused", **EC2).as_text()
+    assert "tpu_custom_call" in text
+    kernels = re.findall(r"%(ei_argmax[.\d]*) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+    assert kernels and all("ei_tail" in op for _, op in kernels)
 
 
 @pytest.fixture(scope="module")
