@@ -73,7 +73,6 @@ from typing import (
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.bayesopt import BOSettings, SearchTrace, trial_budget
 from repro.core.fast_bo import (
@@ -102,8 +101,10 @@ from repro.fleet.sharding import (
     resolve_shard_devices,
     sharded_update,
 )
+from repro.fleet.staging import pack, stage
 from repro.fleet.telemetry import (
-    Telemetry, TimedLock, ei_work, head_slots, recording, span,
+    GroupCounters, Telemetry, TimedLock, ei_work, head_slots, recording,
+    span,
 )
 
 if TYPE_CHECKING:  # import cycle: driver imports session for tune_fleet
@@ -617,16 +618,19 @@ class _LiveChunk:
 class _SpaceEntry:
     """Refcounted per-space cache: the strong reference to the space keeps
     its id() stable for the entry's lifetime; the entry (and the cached
-    encoding/geometry, including a gather layout's (n,n) tensor) is evicted
-    when the last active submission over the space retires."""
+    encoding/geometry, including a gather layout's (n,n) tensor, on the
+    host and on each device a chunk of the space was admitted to) is
+    evicted when the last active submission over the space retires."""
 
-    __slots__ = ("space", "count", "enc", "geom")
+    __slots__ = ("space", "count", "enc", "geom", "dev_geom")
 
     def __init__(self, space):
         self.space = space
         self.count = 0
         self.enc: Optional[np.ndarray] = None
         self.geom: Optional[np.ndarray] = None
+        # device (None: JAX's default placement) → `geom` on that device
+        self.dev_geom: Dict[object, jax.Array] = {}
 
 
 class TuningSession:
@@ -1533,6 +1537,7 @@ class TuningSession:
         bundle with a single chunk takes the plain single-device path.
         """
         S = len(self.shard_devices)
+        counters = self.telemetry.group((shape, cap))
         m = len(members)
         rows = min(_CHUNK, max(2, -(-m // S)))
         out: List[_LiveChunk] = []
@@ -1557,29 +1562,26 @@ class TuningSession:
                     )
                     for k in range(n_shards)
                 ]
+                arrays = [np.stack(xs) for xs in zip(*[
+                    tuple(p[0])
+                    + (self._host_geom(sl[k * rows : (k + 1) * rows], rows),)
+                    + p[1] + self._settings_scalars()
+                    for k, p in enumerate(parts)
+                ])]
             update, sharding = sharded_update(
                 self.shard_devices[:n_shards], self.settings.xi, self.layout
             )
-            with span("tuning.device_put"):
-                state = jax.tree_util.tree_map(
-                    lambda *xs: jax.device_put(np.stack(xs), sharding),
-                    *[p[0] for p in parts],
+            with span("tuning.device_put") as sp:
+                on_dev = [jax.device_put(x, sharding) for x in arrays]
+                self._count_puts(
+                    counters, sp, len(arrays),
+                    sum(x.nbytes for x in arrays),
                 )
-                args = tuple(
-                    jax.device_put(np.stack(xs), sharding)
-                    for xs in zip(*[p[1] for p in parts])
-                ) + tuple(
-                    jax.device_put(np.stack([v] * n_shards), sharding)
-                    for v in (
-                        np.asarray(self.settings.min_observations, np.int32),
-                        np.asarray(self.settings.ei_stop_rel, np.float32),
-                        np.asarray(self.to_exhaustion),
-                    )
-                )
+            n_state = len(FleetState._fields)
             out.append(
                 _LiveChunk(
-                    state=state,
-                    args=args,
+                    state=FleetState(*on_dev[:n_state]),
+                    args=tuple(on_dev[n_state:]),
                     members=sl,
                     capacity=max(cap, 1),
                     update=lambda st, a, _u=update: _u(st, *a),
@@ -1587,7 +1589,7 @@ class TuningSession:
                     n_shards=n_shards,
                     group_key=(shape, cap),
                     t_admit=np.stack([p[0].t for p in parts]),
-                    budget=np.stack([p[1][6] for p in parts]),
+                    budget=np.stack([p[1][5] for p in parts]),
                 )
             )
         return out
@@ -1597,51 +1599,105 @@ class TuningSession:
         resume: Optional[List[FleetState]] = None,
         device=None,
     ) -> _LiveChunk:
+        """One lockstep chunk on ``device`` (None: JAX's default
+        placement; otherwise committed, so the update runs there).
+
+        Its inputs reach the device in one transfer (`staging.pack`),
+        split there by `staging.stage`, which also stacks the rows'
+        geometry from the per-space copies already on the device
+        (`_device_geoms`).  The staged arrays equal the host build of
+        `_chunk_arrays` bit for bit, dummy rows' zero geometry included."""
+        rows = max(len(members), 2)
+        counters = self.telemetry.group((shape, cap))
         with span("tuning.chunk_arrays"):
             state_np, args_np, steps_needed = self._chunk_arrays(
-                members, shape, cap, n_init_slots, max(len(members), 2),
-                resume=resume,
+                members, shape, cap, n_init_slots, rows, resume=resume,
             )
-        tail_np = (
-            np.asarray(self.settings.min_observations, np.int32),
-            np.asarray(self.settings.ei_stop_rel, np.float32),
-            np.asarray(self.to_exhaustion),
-        )
-        with span("tuning.device_put"):
-            if device is None:
-                state = jax.tree_util.tree_map(jnp.asarray, state_np)
-                args = tuple(jnp.asarray(a) for a in args_np) + tuple(
-                    jnp.asarray(v) for v in tail_np
-                )
-            else:
-                # Committed placement: the jitted update runs on ``device``
-                # (identical program and numerics on the identical-ISA host
-                # devices — only WHERE it executes changes, which is how
-                # the service spreads group threads across the forced
-                # topology).
-                put = lambda x: jax.device_put(np.asarray(x), device)
-                state = jax.tree_util.tree_map(put, state_np)
-                args = tuple(put(a) for a in args_np) + tuple(
-                    put(v) for v in tail_np
-                )
+            buf, spec = pack(
+                tuple(state_np) + args_np + self._settings_scalars()
+                + (np.int32(len(members)),)
+            )
+        with span("tuning.device_put") as sp:
+            geoms, puts, nbytes = self._device_geoms(
+                members, device, counters
+            )
+            geom, arrays = stage(
+                jax.device_put(buf, device),
+                geoms + geoms[:1] * (rows - len(members)),
+                spec=spec,
+            )
+            self._count_puts(counters, sp, puts + 1, nbytes + buf.nbytes)
+        n_state = len(FleetState._fields)
         xi, layout = self.settings.xi, self.layout
         return _LiveChunk(
-            state=state,
-            args=args,
+            state=FleetState(*arrays[:n_state]),
+            args=(geom,) + tuple(arrays[n_state:]),
             members=members,
             capacity=max(cap, 1),
             update=lambda st, a: _fleet_update(st, *a, xi=xi, layout=layout),
             steps_needed=steps_needed,
             group_key=(shape, cap),
             t_admit=state_np.t[None],
-            budget=args_np[6][None],
+            budget=args_np[5][None],
         )
+
+    def _settings_scalars(self) -> tuple:
+        """The update's three settings scalars, as host arrays."""
+        return (
+            np.asarray(self.settings.min_observations, np.int32),
+            np.asarray(self.settings.ei_stop_rel, np.float32),
+            np.asarray(self.to_exhaustion),
+        )
+
+    def _device_geoms(
+        self, members: List[_JobRec], device, counters: GroupCounters,
+    ) -> Tuple[Tuple[jax.Array, ...], int, int]:
+        """Each member's space geometry on ``device``, put there once per
+        (space, device) and kept in the space's cache entry until the
+        entry is evicted.  Returns the per-member arrays and the count
+        and bytes of the puts made; counts the group's ``geom_puts`` and
+        ``geom_reuses`` (one per distinct space of the chunk)."""
+        puts = nbytes = 0
+        for space in {id(r.job.space): r.job.space for r in members}.values():
+            entry = self._spaces[id(space)]
+            if device in entry.dev_geom:
+                counters.geom_reuses += 1
+                continue
+            host = self._geom(space)
+            entry.dev_geom[device] = jax.device_put(host, device)
+            counters.geom_puts += 1
+            puts += 1
+            nbytes += host.nbytes
+        return tuple(
+            self._spaces[id(r.job.space)].dev_geom[device] for r in members
+        ), puts, nbytes
+
+    def _host_geom(self, members: List[_JobRec], rows: int) -> np.ndarray:
+        """The (rows, ...) geometry of one chunk built on the host: each
+        member's space geometry, zero for the dummy rows."""
+        one = self._geom(members[0].job.space)
+        geom = np.zeros((rows,) + one.shape, one.dtype)
+        for i, rec in enumerate(members):
+            geom[i] = self._geom(rec.job.space)
+        return geom
+
+    @staticmethod
+    def _count_puts(
+        counters: GroupCounters, sp, puts: int, nbytes: int,
+    ) -> None:
+        """Count a chunk's transfers on its group, and on its
+        ``tuning.device_put`` span while a trace records."""
+        counters.admit_puts += puts
+        counters.admit_bytes += nbytes
+        if recording():
+            sp.set_metadata(puts=puts, bytes=nbytes)
 
     def _chunk_arrays(
         self, members: List[_JobRec], shape, cap: int, n_init_slots: int,
         rows: int, resume: Optional[List[FleetState]] = None,
     ) -> Tuple[FleetState, tuple, int]:
-        """Host-side state/args for one lockstep chunk of ``rows`` rows
+        """Host-side state and args, all but the geometry (see
+        `_build_chunk`, `_host_geom`), for one lockstep chunk of ``rows`` rows
         (members first, then inert dummy rows — zero trial budget, cold
         defaults; rows ≥ 2 because XLA:CPU collapses singleton batch dims
         into unbatched programs with different float32 numerics).
@@ -1656,8 +1712,6 @@ class TuningSession:
         n, d = shape
         capacity = max(cap, 1)
 
-        geom_one = self._geom(members[0].job.space)
-        geom = np.zeros((rows,) + geom_one.shape, geom_one.dtype)
         costs = np.zeros((rows, n), np.float32)
         prio_mask = np.zeros((rows, n), bool)
         rem_mask = np.zeros((rows, n), bool)
@@ -1676,7 +1730,6 @@ class TuningSession:
         last_best0 = np.full(rows, np.inf, np.float32)
 
         for i, rec in enumerate(members):
-            geom[i] = self._geom(rec.job.space)
             costs[i] = rec.table64.astype(np.float32)
             prio_mask[i] = rec.prio_mask
             rem_mask[i] = rec.rem_mask
@@ -1722,8 +1775,7 @@ class TuningSession:
             last_best=last_best0,
         )
         args = (
-            geom, costs, prio_mask, rem_mask, init_picks, init_count,
-            max_trials,
+            costs, prio_mask, rem_mask, init_picks, init_count, max_trials,
         )
         # One extra pass beyond the largest fresh-trial budget: it observes
         # nothing, but it is where a budget-capped job records a phase

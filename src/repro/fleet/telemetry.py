@@ -13,6 +13,7 @@ arguments are attached only while a trace records.
     tuning.admit         TuningSession._admit_group   a non-empty admission
     tuning.chunk_arrays  _build_chunk/_build_sharded  host state and args
     tuning.device_put    _build_chunk/_build_sharded  their transfers
+                                                      (puts, bytes)
     tuning.dispatch      TuningSession._step_chunk    enqueue of one update
                                                       (rows, slots: `ei_work`)
     tuning.poll          TuningSession._step_chunk    the done-flag sync
@@ -23,9 +24,11 @@ arguments are attached only while a trace records.
 Counters are always on and are timed with `time.perf_counter` at the
 boundaries of the matching span: per admission group (`GroupCounters`)
 and session-wide (`Telemetry`, the lock waits).  `TuningService.metrics()`
-reports them.  Four of a group's counters are counts, not times: the
-GP head's column-loop trips on the TPU and its capacity (`head_slots`),
-and the rows and observed slots the EI tail worked on (`ei_work`).
+reports them.  Besides times, a group counts the GP head's column-loop
+trips on the TPU and its capacity (`head_slots`), the rows and observed
+slots the EI tail worked on (`ei_work`), and admission's transfers: all
+of them with their bytes, and those of a space's geometry, which stays
+on the device for the chunks after (`geom_puts`, `geom_reuses`).
 """
 
 from __future__ import annotations
@@ -58,7 +61,8 @@ class GroupCounters:
 
     __slots__ = ("dispatches", "polls", "admissions", "empty_admissions",
                  "admit_s", "dispatch_s", "poll_wait_s", "retire_s",
-                 "head_slots", "head_capacity_slots", "ei_rows", "ei_slots")
+                 "head_slots", "head_capacity_slots", "ei_rows", "ei_slots",
+                 "admit_puts", "admit_bytes", "geom_puts", "geom_reuses")
 
     def __init__(self) -> None:
         self.dispatches = 0  # update enqueues (`tuning.dispatch`)
@@ -79,6 +83,14 @@ class GroupCounters:
         # give them one by one.
         self.ei_rows = 0
         self.ei_slots = 0
+        # Host-to-device transfers at admission and their bytes, as the
+        # `tuning.device_put` spans give them; of them, the puts of a
+        # space's geometry to a device, and the chunks' uses of one
+        # already there (one per distinct space of a chunk).
+        self.admit_puts = 0
+        self.admit_bytes = 0
+        self.geom_puts = 0
+        self.geom_reuses = 0
 
     def as_dict(self) -> dict:
         return {f: getattr(self, f) for f in self.__slots__}

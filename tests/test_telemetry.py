@@ -202,6 +202,51 @@ class TestHeadSlots:
         assert 0 < g["head_slots"] < g["head_capacity_slots"]
 
 
+class TestAdmissionTransfers:
+    def test_one_put_per_chunk_and_per_new_space(self, tmp_path,
+                                                 monkeypatch):
+        """Admission makes one host-to-device transfer per chunk, and one
+        per space whose geometry is not on the device yet.  The counters
+        equal what the ``tuning.device_put`` spans' arguments say, and
+        what the transfers carried."""
+        session = TuningSession(**_session_kwargs())
+        space, table = synth_space_table(24)
+        sent = []
+        put = jax.device_put
+
+        def counting_put(x, *args, **kwargs):
+            sent.append(np.asarray(x).nbytes)
+            return put(x, *args, **kwargs)
+
+        monkeypatch.setattr(jax, "device_put", counting_put)
+
+        def admit(first, count):
+            for s in range(first, first + count):
+                session.submit(FleetJob(name=f"j{s}", space=space,
+                                        cost_table=table),
+                               seed=s, mode="cherrypick")
+            (key,) = session._pending_group_keys()
+            del sent[:]
+            assert session._admit_group(key) == count
+            return len(sent), sum(sent)
+
+        with jax.profiler.trace(str(tmp_path)):
+            # Two chunks (8 + 2 rows) and the space's geometry.
+            puts_a, bytes_a = admit(0, 10)
+            # Chunks of the first admission keep the geometry on device.
+            puts_b, bytes_b = admit(10, 9)
+        assert (puts_a, puts_b) == (3, 2)
+
+        (g,) = session.telemetry.groups().values()
+        assert g["admit_puts"] == 5 and g["admit_bytes"] == bytes_a + bytes_b
+        assert (g["geom_puts"], g["geom_reuses"]) == (1, 3)
+        spans = _named(_spans(str(tmp_path)), "tuning.device_put")
+        assert len(spans) == 4
+        assert all("tuning.admit" in s["parents"] for s in spans)
+        assert sum(s["args"]["puts"] for s in spans) == g["admit_puts"]
+        assert sum(s["args"]["bytes"] for s in spans) == g["admit_bytes"]
+
+
 class TestTimedLock:
     def test_reentrant_and_uncontended_acquires_count_nothing(self):
         tel = Telemetry()

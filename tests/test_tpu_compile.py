@@ -12,15 +12,18 @@ chip).
 """
 
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.core.fast_bo import FleetState
 from repro.fleet.batched_engine import _fleet_update
 from repro.fleet.sharding import sharded_update
+from repro.fleet.staging import _words, stage
 from repro.kernels.ei_argmax import ops as ei_ops
 from repro.kernels.ei_argmax.kernel import ei_argmax_kernel_call
 
@@ -128,6 +131,29 @@ def test_ec2_catalog_fused_update_compiles(one_chip, kernel_lane):
                          r'custom_call_target="tpu_custom_call"[^\n]*'
                          r'op_name="([^"]*)"', text)
     assert kernels and all("ei_tail" in op for _, op in kernels)
+
+
+@pytest.mark.parametrize("dims", [PAPER, EC2], ids=["paper", "ec2"])
+def test_chunk_staging_compiles(one_chip, dims):
+    """Admission's split of one chunk's packed inputs (`staging.stage`)
+    at 8 rows: it gives the update's arrays in their shapes and dtypes,
+    stacks the geometry from the rows' (n, d) device copies, and needs
+    no scratch near the size of what it makes."""
+    state, args = chunk_args(one_chip, (ROWS,), **dims)
+    scalars = [jax.ShapeDtypeStruct((), dt) for dt in
+               (jnp.int32, jnp.float32, bool, jnp.int32)]
+    packed = list(state) + list(args[1:]) + scalars
+    spec = tuple((x.shape, np.dtype(x.dtype)) for x in packed)
+    buf = jax.ShapeDtypeStruct((sum(_words(*s) for s in spec),), jnp.uint32,
+                               sharding=one_chip)
+    geoms = (jax.ShapeDtypeStruct(args[0].shape[1:], jnp.float32,
+                                  sharding=one_chip),) * ROWS
+    geom, arrays = jax.eval_shape(partial(stage, spec=spec), buf, geoms)
+    assert [(x.shape, x.dtype) for x in [geom] + arrays] == [
+        (x.shape, x.dtype) for x in [args[0]] + packed[:-1]]
+    compiled = stage.lower(buf, geoms, spec=spec).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.output_size_in_bytes // 4
 
 
 @pytest.fixture(scope="module")
