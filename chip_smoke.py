@@ -58,7 +58,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from benchmarks.common import JOB_ORDER  # noqa: E402
-from benchmarks.fleet_bench import synthetic_space  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.bayesopt import BOSettings, trial_budget  # noqa: E402
 from repro.core.fast_bo import (  # noqa: E402
@@ -73,7 +72,11 @@ from repro.core.fast_bo import (  # noqa: E402
     packed_sqdist_blocks,
 )
 from repro.kernels.ei_argmax import ei_from_sqdist  # noqa: E402
-from repro.core.search_space import split_search_space  # noqa: E402
+from repro.core.search_space import (  # noqa: E402
+    Configuration,
+    SearchSpace,
+    split_search_space,
+)
 from repro.fleet import FleetJob, TuningService, TuningSession, cluster_fleet  # noqa: E402
 from repro.fleet.batched_engine import _fleet_update  # noqa: E402
 
@@ -166,8 +169,30 @@ def _twice(tag, make_jobs, **kwargs):
     return jobs, outs
 
 
+def synthetic_space(n: int, d: int = 6, seed: int = 7):
+    """An n-config space with random features and a smooth cost surface:
+    (space, (n,) cost table)."""
+    rng = np.random.default_rng(seed + n)
+    feats = rng.normal(size=(n, d))
+    space = SearchSpace(
+        [
+            Configuration(
+                name=f"s{i}",
+                features=tuple(float(v) for v in feats[i]),
+                total_memory=float(i),
+            )
+            for i in range(n)
+        ]
+    )
+    w = rng.normal(size=d)
+    z = feats @ w
+    z = (z - z.mean()) / max(float(z.std()), 1e-9)
+    table = 1.0 + (z - 0.7) ** 2 + 0.05 * rng.random(n)
+    return space, table
+
+
 def catalog_jobs():
-    """Workload G's job construction over one synthetic catalog."""
+    """CATALOG_JOBS CherryPick searches over one synthetic catalog."""
     space, table = synthetic_space(CATALOG_N, CATALOG_D)
     return [FleetJob(name=f"c{i}", space=space, cost_table=table)
             for i in range(CATALOG_JOBS)]

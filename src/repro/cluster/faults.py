@@ -27,9 +27,8 @@ to preserve them:
 
 Stall isolation: under the async service (`repro.fleet.service`) a
 straggler-stalled trial slows only its own admission group's dispatch
-thread — other groups keep stepping at their own pace, which is exactly
-what the open-loop straggler bench (workload G, `benchmarks/fleet_bench`)
-measures against the global-lockstep driver.
+thread — other groups keep stepping at their own pace, where the
+global-lockstep driver stalls them all.
 
 Stochastic transients are capped by ``max_injected`` so a retried call
 site is GUARANTEED to succeed within ``max_injected + 1`` attempts — pick

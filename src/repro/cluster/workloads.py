@@ -176,9 +176,9 @@ def _scenario_catalog() -> Dict[str, JobSpec]:
 def failure_scenario_jobs() -> Dict[str, JobSpec]:
     """Named adversarial-scenario specs derived from the Table I catalog.
 
-    These are the workloads the chaos lane (`pytest -m chaos`) and the
-    adversarial fleet bench disturb: renamed clones whose profiling runs
-    get a `repro.cluster.faults.FaultPlan` attached (flaky / broken), plus
+    These are the workloads the chaos lane (`pytest -m chaos`) disturbs:
+    renamed clones whose profiling runs get a
+    `repro.cluster.faults.FaultPlan` attached (flaky / broken), plus
     drifted recurrences of a linear and a flat job (see `drift_spec`).
     The specs themselves are ordinary `JobSpec`s — the faults live in the
     plan, not the workload, so the same spec serves both the disturbed and
@@ -197,8 +197,7 @@ class PricingScenario:
     ``epoch`` selects the point of the catalog's spot-volatility schedule.
     The interesting scenarios are exactly the ones where the same job's
     cost-optimal configuration (argmin runtime×price under the catalog)
-    differs from its runtime-optimal one (argmin of the legacy book) —
-    fleet_bench workload H asserts that movement.
+    differs from its runtime-optimal one (argmin of the legacy book).
     """
 
     name: str
@@ -262,7 +261,8 @@ def family_constrained_scenarios() -> List[PricingScenario]:
 
 
 def pricing_scenarios(seed: int = 0) -> List[PricingScenario]:
-    """The combined scenario set fleet_bench workload H sweeps."""
+    """The combined scenario set: every spot-volatility and every
+    family-constrained search."""
     return spot_volatility_scenarios(seed) + family_constrained_scenarios()
 
 

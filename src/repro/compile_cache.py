@@ -5,9 +5,8 @@ tuning service's chunk programs take seconds each.  JAX can keep compiled
 executables on disk and find them again in the next process, but only if
 the cache directory is the same every time (the path is part of the key).
 
-`enable_compile_cache` is called by the entry points — `chip_smoke.py`,
-`benchmarks/run.py` and `python -m benchmarks.fleet_bench` — and never by a
-library import or by the tests:
+`enable_compile_cache` is called by the entry points — `chip_smoke.py`
+and `benchmarks/run.py` — and never by a library import or by the tests:
 
   * when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
     nothing is set here;

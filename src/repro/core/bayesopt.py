@@ -189,9 +189,9 @@ def cherrypick_search(
 ) -> SearchTrace:
     """Baseline: plain CherryPick BO over the full space.
 
-    ``layout`` selects the packed engine's geometry path — "feature" (the
-    O(n·d) feature-buffer default) or "gather" (the retained O(n²)
-    d²-gather path, kept for bit-identity cross-checks).
+    ``layout`` selects the packed engine's EI tail — "feature" (the
+    default, the (B,n) cross block in XLA) or "fused" (the streaming
+    EI/argmax kernel); both keep O(n·d) memory.
     """
     return _bo_loop(
         space, cost_fn, rng, [list(range(len(space)))], settings,

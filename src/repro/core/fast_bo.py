@@ -4,22 +4,20 @@ The paper replays every search 200× over a 69-point space; the ROADMAP's
 north star is production-scale spaces (real cloud catalogs span 10⁴–10⁵
 instance-type × count combinations).  At most B points are ever observed
 per search (B = the trial budget, 16–32 in the paper's regime), so the GP
-never needs full-extent linear algebra — and, since PR 3, it never needs
-full-extent *geometry* either: the engine carries a packed **(B,d) feature
-buffer** of the observed points (in trial order) and computes the (B,B)
-training block and the (B,n) cross block on the fly against the static
-(n,d) encoding.  Nothing of extent n×n is ever materialized.
+never needs full-extent linear algebra or full-extent geometry: the engine
+carries a packed **(B,d) feature buffer** of the observed points (in trial
+order) and computes the (B,B) training block and the (B,n) cross block on
+the fly against the static (n,d) encoding.  Nothing of extent n×n is ever
+materialized, so n = 10⁴–10⁵ spaces run in megabytes.
 
 Per-step cost (n = space extent, d = features, B = trial capacity,
-w = warm-start seeds):
+w = warm-start seeds), for the two layouts:
 
     layout           memory      kernel blocks          factorizations  posterior
-    dense            O(n²)       6·O(n²·d)              18·O(n³)        O(n²)
-    d²-gather (PR 2) O(n²)       gathers + 6·O(B²)      18·O(B³)        O(B·n)
     feature (PR 3)   O(n·d)      O(B²d + B·n·d)+6·O(B²) 18·O(B³)        O(B·n)
     fused (PR 8)     O(n·d)      same flops, streamed   18·O(B³)        O(B·tile)
 
-The packed layouts' factorization extent depends on the backend the head
+The layouts' factorization extent depends on the backend the head
 is lowered for (`_packed_head`).  Elsewhere than on the TPU, XLA's
 Cholesky and `cho_solve` factor all B columns: 18·O(B³) per row, the
 column above.  On the TPU, where XLA's Cholesky and triangular inversion
@@ -78,17 +76,10 @@ Session-era paths ride the same step with zero new device code (PR 4):
                      job's own table through untouched (pinned as_dict-
                      equal to the golden fixtures by `-m pricing`)
 
-The d²-gather layout paid a one-off O(n²·d) `precompute_d2` per search and
-held the (n,n) tensor for its whole lifetime — an O(n²) memory wall that
-caps searches near n ≈ 10³.  The feature layout recomputes the two distance
-blocks each step (O(B²d + Bnd), trivially cheap for B ≪ n) from O(n·d)
-state, so n = 10⁴–10⁵ spaces run in megabytes.  All layouts are retained:
-`bo_step_core` (feature) is the default in both engines,
-`bo_step_core_fused` streams its EI/argmax tail through
-`repro.kernels.ei_argmax` (layout="fused", bit-identical — the tail IS the
-same function — with O(B·tile) transients), `bo_step_core_gather` +
-`precompute_d2` are the PR-2 path kept for cross-checking and benchmarking,
-and `bo_step_core_dense` is the original full-extent baseline.
+`bo_step_core` (layout="feature") is the default in both engines;
+`bo_step_core_fused` (layout="fused") streams its EI/argmax tail through
+`repro.kernels.ei_argmax` — bit-identical, since the tail IS the same
+function, with O(B·tile) transients.
 
 Layout.  `FleetState` holds the trial log `tried` (B,), a packed target
 buffer `py` (B,), and the packed feature buffer `feats` (B,d), all aligned
@@ -101,18 +92,19 @@ argmaxes Expected Improvement over the candidate mask.
 
 Bit-identity across layouts.  `packed_sqdist_blocks` computes the (B,n)
 cross block with *exactly* `gp.pairwise_sqdist`'s expansion — sum-of-
-squares per row, one matmul for the cross terms, clamp at zero — which is
-also how `precompute_d2` fills the (n,n) tensor; the contraction axis (d)
-and its summation order are identical whether the left operand has extent
-B or n, so cross rows are bitwise equal to rows of the precomputed tensor.
-The (B,B) training block is then a column gather of the cross block by
-`tried` (a second (B,d)·(d,B) self-matmul can fuse differently from the
-(n,d)·(d,n) one — observed at d = 1 — while gathers are exact), so block
-identity with the d²-gather layout holds by construction (XLA:CPU,
-float32; property-checked in `tests/test_feature_buffer.py`).  Every op
-downstream of the blocks is shared (`_packed_core`), so the two layouts
-produce bit-identical (pick, max_ei, best) — and therefore bit-identical
-search traces.
+squares per row, one matmul for the cross terms, clamp at zero — so its
+rows are bitwise equal to rows of `gp.pairwise_sqdist` over the whole
+space (the contraction axis (d) and its summation order are identical
+whether the left operand has extent B or n).  The (B,B) training block is
+then a column gather of the cross block by `tried` (a second (B,d)·(d,B)
+self-matmul can fuse differently from the (n,d)·(d,n) one — observed at
+d = 1 — while gathers are exact; property-checked in
+`tests/test_feature_buffer.py`).  The fused layout runs the same head
+(`_packed_head`) and evaluates its EI tile by tile with the feature
+layout's own function (`ei_from_sqdist`), so the two layouts produce
+bit-identical (pick, max_ei, best) — and therefore bit-identical search
+traces (`bo_step_core_fused` says how, and why d = 1 runs the feature
+program itself).
 
 Padding is exact, not approximate.  Packed slots ≥ t are masked: their
 kernel rows/columns are zeroed and their diagonal entries set to 1, so the
@@ -128,16 +120,16 @@ occupy slots < t like any observation (index in `tried`, float32 cost in
 padding proof applies verbatim to a seeded buffer — slots ≥ t stay inert,
 slots < t are ordinary training points.
 
-Float32 discipline (unchanged from the dense engine; it concerns the CPU,
-whose head is the full-extent LAPACK one, `_factor_lapack`, bit for bit
-as before — the TPU's column-loop head agrees with it to rounding, not
-bitwise, and computes every product elementwise in float32, never on the
-matrix unit's reduced-precision passes): XLA:CPU float32
-results differ between compilation contexts — batch extent 1 compiles to
-different programs than extents ≥ 2 (hence everything runs at extent ≥ 2),
-extents 2–8 are empirically invariant, ≥ 12 diverge, and `lax.while_loop`
-bodies compute different last-ulp floats (and run 5-8× slower) than the
-same ops standalone.  In the late-search regime one ulp flips argmax picks,
+Float32 discipline (it concerns the CPU, whose head is the full-extent
+LAPACK one, `_factor_lapack`, bit for bit as before — the TPU's column-loop
+head agrees with it to rounding, not bitwise, and computes every product
+elementwise in float32, never on the matrix unit's reduced-precision
+passes): XLA:CPU float32 results differ between compilation contexts —
+batch extent 1 compiles to different programs than extents ≥ 2 (hence
+everything runs at extent ≥ 2), extents 2–8 are empirically invariant, ≥ 12
+diverge, and `lax.while_loop` bodies compute different last-ulp floats (and
+run 5-8× slower) than the same ops standalone.  In the late-search regime
+one ulp flips argmax picks,
 so BOTH engines execute the single `fleet_step` program:
 
   * the fleet engine (`repro.fleet.batched_engine`) vmaps it over lockstep
@@ -155,13 +147,10 @@ so BOTH engines execute the single `fleet_step` program:
     scalars come back — no per-iteration copies of any state buffer.
 
 `tests/test_fleet.py` asserts sequential↔batched trace identity
-seed-for-seed (both layouts, and feature↔gather cross-layout);
-`tests/test_feature_buffer.py` property-checks the feature blocks against
-the d²-gather blocks and `gp.pairwise_sqdist` bit-for-bit, including
-padded-slot inertness; `tests/test_core_bo.py` checks the packed math
-against the readable reference in `gp.py`/`acquisition.py` and the
-retained dense path (`bo_step_core_dense`, the full-extent baseline for
-`benchmarks/fleet_bench.py`'s scaling sweep).
+seed-for-seed; `tests/test_feature_buffer.py` property-checks the feature
+blocks against `gp.pairwise_sqdist` bit-for-bit, including padded-slot
+inertness; `tests/test_core_bo.py` checks the packed math of both layouts
+against the readable reference in `gp.py`/`acquisition.py`.
 """
 
 from __future__ import annotations
@@ -182,21 +171,17 @@ __all__ = [
     "SequentialProbe",
     "bo_step",
     "bo_step_core",
-    "bo_step_core_dense",
     "bo_step_core_fused",
-    "bo_step_core_gather",
     "encode_features",
     "fleet_step",
-    "gather_sqdist_blocks",
     "packed_sqdist_blocks",
-    "precompute_d2",
 ]
 
 _JITTER = 1e-8
 _LENGTHSCALES = (0.1, 0.25, 0.5, 1.0, 2.0, 4.0)
 _NOISES = (1e-4, 1e-2, 1e-1)
 
-_LAYOUTS = ("feature", "gather", "fused")
+_LAYOUTS = ("feature", "fused")
 
 # The name scope of the extent-n EI tail in the feature and fused layouts:
 # the cross distance block, posterior, EI and argmax over the candidates
@@ -216,23 +201,6 @@ def encode_features(encoded) -> np.ndarray:
     return np.asarray(encoded, np.float32)
 
 
-@jax.jit
-def _pairwise_sqdist_f32(encoded: jax.Array) -> jax.Array:
-    return pairwise_sqdist(encoded.astype(jnp.float32))
-
-
-def precompute_d2(encoded) -> jax.Array:
-    """(n,n) raw pairwise squared distances over the encoded space, float32.
-
-    The PR-2 d²-gather layout: computed once per search — UNBATCHED, so
-    sequential and fleet runs of the same space get bit-identical tensors —
-    and threaded through every step as a constant.  O(n²) memory; retained
-    for cross-checking the feature-buffer layout and for benchmarking, not
-    used by the default engines.
-    """
-    return _pairwise_sqdist_f32(jnp.asarray(encode_features(encoded)))
-
-
 def packed_sqdist_blocks(
     feats: jax.Array,  # (B, d) packed features of observed points
     encoded: jax.Array,  # (n, d) static encoding of the whole space
@@ -242,31 +210,18 @@ def packed_sqdist_blocks(
 
     The (B,n) cross block is `gp.pairwise_sqdist`'s expansion verbatim —
     same sum-of-squares, same matmul contraction over d, same clamp — and
-    its rows are bitwise equal to rows of `precompute_d2`'s (n,n) tensor
-    (the contraction axis and its order are identical whether the left
-    operand has extent B or n).  The (B,B) training block is then a COLUMN
-    GATHER of the cross block by `tried`, not a second matmul: a
+    its rows are bitwise equal to rows of `gp.pairwise_sqdist` over the
+    whole space (the contraction axis and its order are identical whether
+    the left operand has extent B or n).  The (B,B) training block is then
+    a COLUMN GATHER of the cross block by `tried`, not a second matmul: a
     (B,d)·(d,B) self-product can fuse differently from the (n,d)·(d,n)
     one (observed at d = 1 on XLA:CPU, last-ulp), while gathers are exact
-    — so block identity with the d²-gather layout holds by construction.
+    — so its entries are bitwise those of the whole-space distances.
     O(Bnd) compute and O(Bn) memory; nothing of extent n² exists.
     """
     d2_bn = pairwise_sqdist(feats, encoded)
     idx = jnp.maximum(tried, 0)  # padded slots gather column 0; masked later
     return d2_bn[:, idx], d2_bn
-
-
-def gather_sqdist_blocks(
-    d2: jax.Array,  # (n, n) precomputed raw squared distances
-    tried: jax.Array,  # (B,) i32 trial log, -1 padded
-) -> Tuple[jax.Array, jax.Array]:
-    """((B,B), (B,n)) blocks gathered from the precomputed (n,n) tensor.
-
-    The PR-2 layout; padded slots gather row 0 (finite garbage, masked
-    exactly downstream).
-    """
-    idx = jnp.maximum(tried, 0)
-    return d2[idx[:, None], idx[None, :]], d2[idx]
 
 
 def _masked_posterior(
@@ -478,35 +433,6 @@ def _packed_head(
     )
 
 
-def _packed_core(
-    d2_bb: jax.Array,  # (B, B) raw squared distances, training block
-    d2_bn: jax.Array,  # (B, n) raw squared distances, cross block
-    py: jax.Array,  # (B,) f32 packed observed costs, trial order
-    t: jax.Array,  # () i32 observations made (valid packed slots)
-    obs_mask: jax.Array,  # (n,) bool — configurations already tried
-    cand_mask: jax.Array,  # (n,) bool — current candidate pool
-    xi: float,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Everything downstream of the distance blocks, shared verbatim by the
-    feature-buffer and d²-gather layouts — the op-for-op identity of this
-    tail is what makes the two layouts' picks bit-identical.  The EI math
-    itself is `ei_from_sqdist`, the SAME function the fused layout's tiled
-    lanes execute per (B,tile) block (`repro.kernels.ei_argmax`), so the
-    unfused reference and the fused kernel cannot drift apart.
-    """
-    pm, best, ls_sel, chol, alpha, y_mean, y_std = _packed_head(d2_bb, py, t)
-    # Posterior + EI over all n points for the selected hyperparameters
-    # only: one (B,n) rescale of the cross block, masked training rows.
-    with jax.named_scope(EI_TAIL_SCOPE):
-        ei = ei_from_sqdist(
-            d2_bn, pm[:, None], alpha, chol, ls_sel, y_mean, y_std, best,
-            cand_mask & ~obs_mask, xi,
-        )
-        pick = jnp.argmax(ei)
-        max_ei = jnp.max(ei)
-    return pick, max_ei, best
-
-
 def bo_step_core(
     encoded: jax.Array,  # (n, d) static float32 encoding of the whole space
     feats: jax.Array,  # (B, d) packed features of observed points, trial order
@@ -523,12 +449,25 @@ def bo_step_core(
     All training-side linear algebra runs at the packed capacity B; the
     space extent n only appears in the O(Bnd) cross-block matmul, the (B,n)
     rescale, and the EI argmax.  Nothing of extent n² exists anywhere.
-    The extent-n work (the cross block, then `_packed_core`'s tail) runs
-    under the ``ei_tail`` name scope.
+    The extent-n work (the cross block, then the posterior, EI and argmax)
+    runs under the ``ei_tail`` name scope.  The EI math is
+    `ei_from_sqdist`, the SAME function the fused layout's tiled lanes
+    execute per (B,tile) block (`repro.kernels.ei_argmax`), so the unfused
+    reference and the fused kernel cannot drift apart.
     """
     with jax.named_scope(EI_TAIL_SCOPE):
         d2_bb, d2_bn = packed_sqdist_blocks(feats, encoded, tried)
-    return _packed_core(d2_bb, d2_bn, py, t, obs_mask, cand_mask, xi)
+    pm, best, ls_sel, chol, alpha, y_mean, y_std = _packed_head(d2_bb, py, t)
+    # Posterior + EI over all n points for the selected hyperparameters
+    # only: one (B,n) rescale of the cross block, masked training rows.
+    with jax.named_scope(EI_TAIL_SCOPE):
+        ei = ei_from_sqdist(
+            d2_bn, pm[:, None], alpha, chol, ls_sel, y_mean, y_std, best,
+            cand_mask & ~obs_mask, xi,
+        )
+        pick = jnp.argmax(ei)
+        max_ei = jnp.max(ei)
+    return pick, max_ei, best
 
 
 def bo_step_core_fused(
@@ -587,105 +526,13 @@ def bo_step_core_fused(
     return pick, max_ei, best
 
 
-def bo_step_core_gather(
-    d2: jax.Array,  # (n, n) raw pairwise squared distances (precompute_d2)
-    tried: jax.Array,  # (B,) i32 trial log in trial order, -1 padded
-    py: jax.Array,  # (B,) f32 packed observed costs, aligned with tried
-    t: jax.Array,  # () i32 observations made (valid packed slots)
-    obs_mask: jax.Array,  # (n,) bool — configurations already tried
-    cand_mask: jax.Array,  # (n,) bool — current candidate pool
-    xi: float = 0.0,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The retained PR-2 d²-gather BO iteration: blocks gathered from the
-    once-per-search (n,n) tensor instead of recomputed from features.
-
-    Kept as the cross-check for the feature-buffer layout (the two must be
-    bit-identical — `tests/test_feature_buffer.py`) and for the scaling
-    sweep in `benchmarks/fleet_bench.py`.  Not used by the default engines.
-    """
-    d2_bb, d2_bn = gather_sqdist_blocks(d2, tried)
-    return _packed_core(d2_bb, d2_bn, py, t, obs_mask, cand_mask, xi)
-
-
-def bo_step_core_dense(
-    encoded: jax.Array,  # (n, d) standardized features of the whole space
-    obs_mask: jax.Array,  # (n,) bool — configurations already tried
-    y: jax.Array,  # (n,) observed costs (garbage where not observed)
-    cand_mask: jax.Array,  # (n,) bool — current candidate pool
-    xi: float = 0.0,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The pre-packed full-extent BO step: O(18n³) per call.
-
-    Retained as the dense baseline `benchmarks/fleet_bench.py` times the
-    packed layouts against, and as a second reference for the packed math
-    in `tests/test_core_bo.py`.  Not used by either search engine.
-    """
-    x = encoded.astype(jnp.float32)
-    m = obs_mask.astype(x.dtype)
-    n_obs = jnp.maximum(jnp.sum(m), 1.0)
-    y = y.astype(x.dtype)
-    y_mean = jnp.sum(y * m) / n_obs
-    y_var = jnp.sum(m * (y - y_mean) ** 2) / n_obs
-    y_std = jnp.maximum(jnp.sqrt(y_var), 1e-8)
-    y_n = jnp.where(obs_mask, (y - y_mean) / y_std, 0.0)
-
-    ls = jnp.asarray(_LENGTHSCALES, x.dtype)
-    nz = jnp.asarray(_NOISES, x.dtype)
-    d2 = pairwise_sqdist(x)
-    ks = jax.vmap(lambda l: matern52_from_sqdist(d2, l))(ls)  # (6, n, n)
-
-    mm = m[:, None] * m[None, :]
-    y_train = y_n * m
-    ks_masked = ks * mm[None]  # (6, n, n)
-    diag_idx = jnp.arange(ks.shape[-1])
-
-    def factorize(k_masked, noise):
-        diag = jnp.where(obs_mask, noise + _JITTER, 1.0)
-        k_eff = k_masked.at[diag_idx, diag_idx].add(diag)
-        chol = jnp.linalg.cholesky(k_eff)
-        alpha = jax.scipy.linalg.cho_solve((chol, True), y_train)
-        lml = (
-            -0.5 * y_train @ alpha
-            - jnp.sum(jnp.log(jnp.diagonal(chol)) * m)
-            - 0.5 * jnp.sum(m) * jnp.log(2.0 * jnp.pi)
-        )
-        return lml, chol, alpha
-
-    ks18 = jnp.repeat(ks_masked, nz.shape[0], axis=0)  # (18, n, n)
-    nz18 = jnp.tile(nz, ls.shape[0])  # (18,)
-    lmls, chols, alphas = jax.vmap(factorize)(ks18, nz18)
-    lmls = jnp.where(jnp.isfinite(lmls), lmls, -jnp.inf)
-    best_h = jnp.argmax(lmls)
-
-    k_star = ks[best_h // nz.shape[0]] * m[:, None]  # masked training rows
-    mean_n = k_star.T @ alphas[best_h]
-    v = jax.scipy.linalg.solve_triangular(chols[best_h], k_star, lower=True)
-    var_n = jnp.maximum(1.0 - jnp.sum(v * v, axis=0), 1e-12)
-    std_n = jnp.sqrt(var_n)
-
-    mean = mean_n * y_std + y_mean
-    std = std_n * y_std
-
-    best = jnp.min(jnp.where(obs_mask, y, jnp.inf))
-    improvement = best - mean - xi
-    z = improvement / jnp.maximum(std, 1e-12)
-    cdf = 0.5 * (1.0 + jax.scipy.special.erf(z / jnp.sqrt(2.0)))
-    pdf = jnp.exp(-0.5 * z * z) / jnp.sqrt(2.0 * jnp.pi)
-    ei = jnp.maximum(improvement * cdf + std * pdf, 0.0)
-    ei = jnp.where(cand_mask & ~obs_mask, ei, -jnp.inf)
-    pick = jnp.argmax(ei)
-    return pick, jnp.max(ei), best
-
-
 class FleetState(NamedTuple):
     """Per-job search state, device-resident between `fleet_step` calls.
 
     The packed buffers (`tried`, `py`, `feats`) have static capacity B =
     the job's trial budget; slot k holds the k-th observation, in trial
-    order.  `feats` carries the observed points' encoded features — the
-    feature-buffer layout computes its kernel blocks from it, the d²-gather
-    layout carries it untouched (zeros) so both layouts share one state
-    type and one donation contract.
+    order.  `feats` carries the observed points' encoded features, from
+    which both layouts compute their kernel blocks.
     """
 
     obs: jax.Array  # (n,) bool — observation mask over the space
@@ -702,7 +549,7 @@ class FleetState(NamedTuple):
 
 def fleet_step(
     state: FleetState,
-    geom: jax.Array,  # (n,d) encoded [feature layout] | (n,n) d2 [gather]
+    geom: jax.Array,  # (n, d) encoding
     costs: jax.Array,  # (n,) f32 — full observation table
     prio_mask: jax.Array,  # (n,) bool — priority pool (phase 0)
     rem_mask: jax.Array,  # (n,) bool — remaining pool (phase 1)
@@ -720,12 +567,11 @@ def fleet_step(
     complete two-phase search; semantics mirror
     `repro.core.bayesopt._bo_loop` exactly.  A no-op once the job is done.
 
-    ``layout`` is trace-static: "feature" (default) takes the (n,d)
-    encoding as ``geom`` and maintains the packed feature buffer; "fused"
-    takes the same geometry and buffer but streams the n-extent tail
-    through the fused EI/argmax kernel (`bo_step_core_fused` — no (B,n)
-    block); "gather" takes the precomputed (n,n) distance tensor (the
-    retained PR-2 path) and leaves ``state.feats`` untouched.
+    ``layout`` is trace-static: "feature" (default) computes the (B,n)
+    cross block from the (n,d) encoding ``geom`` and the packed feature
+    buffer; "fused" takes the same geometry and buffer but streams the
+    n-extent tail through the fused EI/argmax kernel (`bo_step_core_fused`
+    — no (B,n) block).
     """
     if layout not in _LAYOUTS:
         raise ValueError(f"unknown layout {layout!r}; want one of {_LAYOUTS}")
@@ -754,13 +600,9 @@ def fleet_step(
         bo_pick, max_ei, best = bo_step_core(
             geom, feats, tried, py, t, obs, cand, xi
         )
-    elif layout == "fused":
+    else:
         bo_pick, max_ei, best = bo_step_core_fused(
             geom, feats, tried, py, t, obs, cand, xi
-        )
-    else:
-        bo_pick, max_ei, best = bo_step_core_gather(
-            geom, tried, py, t, obs, cand, xi
         )
     scripted = init_picks[jnp.clip(t, 0, n_init_slots - 1)]
     pick = jnp.where(is_init, scripted, bo_pick).astype(jnp.int32)
@@ -781,10 +623,9 @@ def fleet_step(
     obs = jnp.where(observe, obs.at[pick].set(True), obs)
     tried = jnp.where(observe, tried.at[slot].set(pick), tried)
     py = jnp.where(observe, py.at[slot].set(costs[pick]), py)
-    if layout in ("feature", "fused"):
-        # The observed point's features enter the packed buffer — the only
-        # geometry the next step's kernel blocks will read.
-        feats = jnp.where(observe, feats.at[slot].set(geom[pick]), feats)
+    # The observed point's features enter the packed buffer — the only
+    # geometry the next step's kernel blocks will read.
+    feats = jnp.where(observe, feats.at[slot].set(geom[pick]), feats)
     t = t + observe.astype(jnp.int32)
     # A job is done when its candidates ran out, its stop criterion halted
     # it, or its trial budget is exhausted (the last also settles zero-budget
@@ -849,11 +690,10 @@ class SequentialProbe:
     for the same job — both engines then factorize (B,B) systems of the
     same static extent, which is what keeps their traces bit-identical.
 
-    ``layout="feature"`` (default) keeps only the (n,d) encoding on device
-    — O(n·d) memory, the 10⁴–10⁵-point regime; ``layout="fused"`` keeps
-    the same encoding and streams the EI tail through the fused kernel
-    (O(B·tile) transients, bit-identical picks); ``layout="gather"`` is
-    the retained PR-2 path holding the (n,n) distance tensor.
+    Either layout keeps only the (n,d) encoding on device — O(n·d)
+    memory, the 10⁴–10⁵-point regime; ``layout="fused"`` streams the EI
+    tail through the fused kernel (O(B·tile) transients, bit-identical
+    picks).
     """
 
     def __init__(self, encoded, capacity: int, xi: float = 0.0,
@@ -866,10 +706,7 @@ class SequentialProbe:
         self._xi = float(xi)
         self._layout = layout
         self._enc = enc
-        if layout in ("feature", "fused"):
-            geom = jnp.asarray(enc)
-        else:
-            geom = precompute_d2(enc)
+        geom = jnp.asarray(enc)
         self._geom2 = jnp.stack([geom, geom])
         # Observation values are irrelevant inside the probe: the real cost
         # arrives via `last_cost` on the following call.

@@ -77,9 +77,8 @@ def pairwise_sqdist(x1: jax.Array, x2: jax.Array = None) -> jax.Array:
     """Raw pairwise squared Euclidean distances between (n,d) and (m,d).
 
     Lengthscale-free: a scalar lengthscale only rescales d² (d²/ls²), so one
-    precomputed tensor serves every point of a lengthscale grid — and, in
-    `fast_bo`, every step of a whole search.  Clamped at zero (the quadratic
-    expansion can go slightly negative in float32).  The cross term runs at
+    tensor serves every point of a lengthscale grid.  Clamped at zero (the
+    quadratic expansion can go slightly negative in float32).  The cross term runs at
     full float32 precision: a TPU's default precision would round it to
     bfloat16 (XLA:CPU ignores the setting for float32).
     """

@@ -21,15 +21,11 @@ devices and advanced by one `shard_map` dispatch — see
     iterations (all bookkeeping — including per-job stopping — happens on
     device, and iterations dispatch asynchronously, so there are no
     per-step host round-trips);
-  * per-job geometry is the static (n,d) float32 encoding — the
-    feature-buffer layout computes its (B,B)/(B,n) distance blocks on the
-    fly from the packed (B,d) feature buffer each step, so nothing of
-    extent n² is ever materialized and 10⁴–10⁵-point spaces run in O(n·d)
-    memory.  The retained PR-2 path (``layout="gather"``) instead threads
-    each job's precomputed (n,n) distance tensor (`fast_bo.precompute_d2`)
-    through every iteration; the two layouts are bit-identical
-    (`tests/test_feature_buffer.py`) and the gather path is kept for
-    cross-checking and benchmarking;
+  * per-job geometry is the static (n,d) float32 encoding — both layouts
+    compute their (B,B)/(B,n) distances on the fly from the packed (B,d)
+    feature buffer each step (``layout="fused"`` streams the (B,n) part
+    through the fused EI/argmax kernel), so nothing of extent n² is ever
+    materialized and 10⁴–10⁵-point spaces run in O(n·d) memory;
   * `fleet_step` is the *same compiled program* the sequential path probes,
     so the two engines are trace-identical — `tests/test_fleet.py` asserts
     equal `tried`/`costs`/`stop_iteration` sequences seed-for-seed.  (A
@@ -123,7 +119,7 @@ def _fleet_update(
     The state is donated: its buffers alias the outputs, so each fleet
     iteration updates in place — no per-iteration device copies of the
     observation mask or the packed trial/target/feature buffers (asserted
-    by `benchmarks/fleet_bench.py`).
+    by `tests/test_fleet.py`).
     """
 
     def one(s, g, c, p, r, ip, ic, mt):
@@ -175,9 +171,9 @@ def batched_search(
     each job's Ruya split (omitted → plain CherryPick over the whole space).
     The random initialization consumes ``rngs[j]`` exactly like the
     sequential engine, so seed-matched runs produce identical traces.
-    ``layout`` selects the packed geometry path: "feature" (default, O(n·d)
-    memory) or "gather" (retained PR-2 (n,n)-tensor path, bit-identical,
-    kept for cross-checks — do not use it for n ≳ 10⁴ spaces).
+    ``layout`` selects the EI tail: "feature" (default, the (B,n) cross
+    block in XLA) or "fused" (the streaming EI/argmax kernel); both hold
+    O(n·d) memory.
     ``shard``/``devices`` shard the job axis across JAX devices
     (`repro.fleet.sharding`) — a pure execution optimization, pinned
     bit-identical to the single-device default by `tests/golden/`.

@@ -16,8 +16,8 @@ cross-job warm-starting.
 `cluster_fleet` replays paper workloads through `repro.cluster.simulator`;
 `replay_seeds` expands one job into a fleet of seed-replicas — the paper's
 "repeat every search 200×" protocol becomes a single batched call (and,
-since seed-replicas share one `SearchSpace` object, one distance-tensor
-precompute serves the whole replica fleet).
+since seed-replicas share one `SearchSpace` object, one cached encoding
+serves the whole replica fleet).
 """
 
 from __future__ import annotations
@@ -126,9 +126,9 @@ def cluster_fleet(
                 sim = ClusterSimulator.for_job(key)
         # A priced job's base table is its normalized RUNTIME axis, so
         # objective="runtime" means fastest and objective="cost" means
-        # cheapest under the same catalog — the two-objective contrast
-        # workload H measures.  Unpriced jobs keep the legacy normalized
-        # table byte-for-byte (the paper's metric, and every pinned trace).
+        # cheapest under the same catalog.  Unpriced jobs keep the legacy
+        # normalized table byte-for-byte (the paper's metric, and every
+        # pinned trace).
         table = (
             sim.normalized if sim.runtime_h is None
             else sim.runtime_h / sim.runtime_h.min()

@@ -31,9 +31,7 @@ behavior is to block (default) or raise `ServiceSaturated`.  Admitted
 groups spread across the host devices round-robin (committed placement —
 identical programs and numerics on identical host devices, only WHERE
 they run changes), so two groups' dispatch loops genuinely overlap:
-group A's device wait no longer stalls group B's dispatch, which is the
-stall-isolation property the straggler bench (workload G in
-`benchmarks/fleet_bench.py`) measures.
+group A's device wait no longer stalls group B's dispatch.
 
 Lock discipline (the deadlock-freedom argument): the session lock is the
 OUTER lock — outcome listeners fire under it and may take the service
@@ -45,7 +43,7 @@ idle-exit check) take the session lock first, then the CV.
 by a group's worker before each of its iterations, outside all locks.
 The interleaving-fuzz suite drives seeded sleeps through it; the
 disturbed golden scenario uses it to hold a group mid-flight while the
-test cancels a victim and reshards; workload G injects straggler delay.
+test cancels a victim and reshards.
 """
 
 from __future__ import annotations

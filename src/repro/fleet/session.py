@@ -28,7 +28,7 @@ exact array program — same grouping, same chunking, same scripted-init
 draws in submission order, same singleton dummy padding, same jitted update
 — and is bitwise trace-identical to the pre-session engines
 (`tests/test_session.py` pins this seed-for-seed against the sequential
-engine for both packed geometry layouts).
+engine for both layouts).
 
 Cross-job warm-starting (Flora's signature classes, Blink's recurring-job
 amortization).  The session owns the tuning state: give it a
@@ -75,12 +75,7 @@ import numpy as np
 import jax
 
 from repro.core.bayesopt import BOSettings, SearchTrace, trial_budget
-from repro.core.fast_bo import (
-    _LAYOUTS,
-    FleetState,
-    encode_features,
-    precompute_d2,
-)
+from repro.core.fast_bo import _LAYOUTS, FleetState, encode_features
 from repro.core.profiler import (
     ProfileResult,
     ProfilingRunError,
@@ -618,18 +613,17 @@ class _LiveChunk:
 class _SpaceEntry:
     """Refcounted per-space cache: the strong reference to the space keeps
     its id() stable for the entry's lifetime; the entry (and the cached
-    encoding/geometry, including a gather layout's (n,n) tensor, on the
-    host and on each device a chunk of the space was admitted to) is
-    evicted when the last active submission over the space retires."""
+    (n, d) encoding, on the host and on each device a chunk of the space
+    was admitted to) is evicted when the last active submission over the
+    space retires."""
 
-    __slots__ = ("space", "count", "enc", "geom", "dev_geom")
+    __slots__ = ("space", "count", "enc", "dev_geom")
 
     def __init__(self, space):
         self.space = space
         self.count = 0
         self.enc: Optional[np.ndarray] = None
-        self.geom: Optional[np.ndarray] = None
-        # device (None: JAX's default placement) → `geom` on that device
+        # device (None: JAX's default placement) → `enc` on that device
         self.dev_geom: Dict[object, jax.Array] = {}
 
 
@@ -687,8 +681,8 @@ class TuningSession:
     history (``drift_events`` logs the job names).
 
     Finished jobs release their per-job state: cost tables, masks, cached
-    encodings and geometry (refcounted per space — a gather layout's (n,n)
-    tensor is evicted with its last job) are dropped at retirement, so a
+    encodings (refcounted per space, on the host and on each device, and
+    evicted with the space's last job) are dropped at retirement, so a
     long-lived service session holds only the outcomes and the per-class
     trial history (bounded by deduplication at ≤ n entries per class).
     """
@@ -767,7 +761,7 @@ class TuningSession:
         self._chunks: List[_LiveChunk] = []
         self._order: List[JobHandle] = []  # submission order
         self._outcomes: Dict[int, SearchOutcome] = {}
-        # id(space) → refcounted encoding/geometry (strong space ref inside)
+        # id(space) → refcounted encoding (strong space ref inside)
         self._spaces: Dict[int, _SpaceEntry] = {}
         # id(job) → [job, active submissions, profile, profiling attempts,
         # charged backoff seconds, drift flag]; evicted at zero refcount
@@ -1477,7 +1471,7 @@ class TuningSession:
 
     def _release(self, rec: _JobRec) -> None:
         """Drop the retired job's share of the caches; evict empty entries
-        (including a gather layout's (n,n) geometry tensor)."""
+        (with their encodings on the host and on the devices)."""
         sid = id(rec.job.space)
         se = self._spaces.get(sid)
         if se is not None:
@@ -1492,23 +1486,12 @@ class TuningSession:
                 del self._jobs[jid]
 
     def _encoding(self, space) -> np.ndarray:
+        """The space's (n, d) float32 encoding, once per space (seed-replica
+        fleets alias one SearchSpace)."""
         entry = self._spaces[id(space)]
         if entry.enc is None:
             entry.enc = encode_features(space.encoded())
         return entry.enc
-
-    def _geom(self, space) -> np.ndarray:
-        """Per-space geometry, once per space (seed-replica fleets alias one
-        SearchSpace): the (n,d) encoding (feature and fused layouts) or the
-        (n,n) distance tensor (retained gather layout)."""
-        entry = self._spaces[id(space)]
-        if entry.geom is None:
-            enc = self._encoding(space)
-            entry.geom = (
-                enc if self.layout in ("feature", "fused")
-                else np.asarray(precompute_d2(enc))
-            )
-        return entry.geom
 
     def _admit(self) -> None:
         """Form lockstep chunks from the pending queue — the same (space
@@ -1652,7 +1635,7 @@ class TuningSession:
     def _device_geoms(
         self, members: List[_JobRec], device, counters: GroupCounters,
     ) -> Tuple[Tuple[jax.Array, ...], int, int]:
-        """Each member's space geometry on ``device``, put there once per
+        """Each member's space encoding on ``device``, put there once per
         (space, device) and kept in the space's cache entry until the
         entry is evicted.  Returns the per-member arrays and the count
         and bytes of the puts made; counts the group's ``geom_puts`` and
@@ -1663,7 +1646,7 @@ class TuningSession:
             if device in entry.dev_geom:
                 counters.geom_reuses += 1
                 continue
-            host = self._geom(space)
+            host = self._encoding(space)
             entry.dev_geom[device] = jax.device_put(host, device)
             counters.geom_puts += 1
             puts += 1
@@ -1673,12 +1656,12 @@ class TuningSession:
         ), puts, nbytes
 
     def _host_geom(self, members: List[_JobRec], rows: int) -> np.ndarray:
-        """The (rows, ...) geometry of one chunk built on the host: each
-        member's space geometry, zero for the dummy rows."""
-        one = self._geom(members[0].job.space)
+        """The (rows, n, d) geometry of one chunk built on the host: each
+        member's space encoding, zero for the dummy rows."""
+        one = self._encoding(members[0].job.space)
         geom = np.zeros((rows,) + one.shape, one.dtype)
         for i, rec in enumerate(members):
-            geom[i] = self._geom(rec.job.space)
+            geom[i] = self._encoding(rec.job.space)
         return geom
 
     @staticmethod

@@ -55,9 +55,7 @@ shard-count-independent; per-shard retirement is future work.
 On CPU, multiple devices come from
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — set before the
 JAX backend initializes (`repro.hostdevices.force_host_device_count`,
-used by the tests' ``conftest.py`` and by
-``benchmarks/run.py``/``benchmarks/fleet_bench.py`` when the fleet suite
-runs).  A sharded session degrades loudly, not silently: asking for more
+used by the tests' ``conftest.py`` and ``tests/golden/regen.py``).  A sharded session degrades loudly, not silently: asking for more
 shards than there are devices raises, while ``shard="auto"`` uses
 whatever is available (1 device → the unsharded reference path).
 """

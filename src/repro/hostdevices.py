@@ -4,8 +4,8 @@
 environment BEFORE the JAX backend initializes, which makes it an
 entry-point concern: `tests/conftest.py` (the in-process shard test
 lanes), `tests/golden/regen.py` (fixture regeneration under the test
-topology), `benchmarks/run.py` and `benchmarks/fleet_bench.py` (the
-`--shards` sweep) all need the same guard.  THIS module is the one copy
+topology) and the `repro.launch` dry runs (placeholder production
+meshes) all need the same guard.  THIS module is the one copy
 of it — deliberately jax-free, so importing it can never initialize the
 backend it is trying to configure.
 

@@ -37,8 +37,8 @@ from repro.kernels.ei_argmax.tile import ei_from_sqdist
 __all__ = ["ei_argmax"]
 
 # 1024-wide tiles: B=24 tiles are ~100 KB transient, and the scan lane's
-# per-step time is flat across 512–8192 on the CPU backend (measured in
-# benchmarks/fleet_bench.py) — small spaces shrink to one 128-multiple tile.
+# per-step time is flat across 512–8192 on the CPU backend — small spaces
+# shrink to one 128-multiple tile.
 _DEFAULT_TILE = 1024
 _MIN_TILE = 128
 

@@ -3,7 +3,7 @@
 Everything downstream of a raw squared-distance block — Matérn-5/2
 rescale, posterior mean/variance against the packed training factors,
 de-standardization, and Expected Improvement — lives in this ONE function.
-The unfused reference lane (`repro.core.fast_bo._packed_core`) calls it on
+The unfused reference lane (`repro.core.fast_bo.bo_step_core`) calls it on
 the full (B,n) cross block; the fused lanes (the Pallas kernel body, its
 interpret-mode twin, and the `lax.scan` CPU lane in `.ops`) call it on
 (B,tile) blocks.  Sharing the function — not just the formulation — is

@@ -89,7 +89,7 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
         return
     elapsed = time.time() - t0
     markexpr = (getattr(config.option, "markexpr", "") or "").strip()
-    is_tier1 = markexpr == "not slow and not bench_smoke"
+    is_tier1 = markexpr == "not slow"
     top = sorted(_DURATIONS.items(), key=lambda kv: kv[1], reverse=True)[:10]
     terminalreporter.write_line(
         f"{'tier-1' if is_tier1 else 'lane'} wall clock {elapsed:.0f}s — "

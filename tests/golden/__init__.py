@@ -2,7 +2,7 @@
 
 The committed JSON fixtures under this directory pin the engines'
 bit-exact behavior on three scenarios (see `.scenarios`): every engine
-variant — unsharded/sharded, feature/gather layout, session API or legacy
+variant — unsharded/sharded, feature/fused layout, session API or legacy
 shim — must reproduce the fixture traces verbatim.  `assert_outcomes_match`
 is THE assertion every lane uses; `assert_traces_match` adapts it to the
 legacy `SearchTrace` view for the `batched_search`/`tune_fleet` shims.

@@ -5,7 +5,7 @@ Each scenario builds a deterministic workload and runs it through a
 variant under test is injected via ``layout`` / ``shard`` — the committed
 fixtures are generated from the UNSHARDED feature-layout session after
 `tests.golden.regen` cross-checks it against the sequential engine, and
-every other lane (gather layout, shard counts 2/4, the legacy shims) must
+every other lane (fused layout, shard counts 2/4, the legacy shims) must
 reproduce the same bits.
 
 Scenario catalog (ISSUE 5's pinned set):

@@ -1,14 +1,14 @@
 """Admission stages a chunk's inputs in one transfer and keeps each
-space's geometry on the device (`repro.fleet.staging`, `TuningSession.
+space's encoding on the device (`repro.fleet.staging`, `TuningSession.
 _build_chunk`).
 
 The arrays a new chunk hands to its update must equal, in dtype, shape
 and bytes, a host build of the chunk: `_chunk_arrays`' state and args,
-each row's space geometry (zero for dummy rows) and the settings
-scalars.  Covered: every geometry layout, row extents 2 (one member and a
-dummy), 5 and 8, a chunk mixing two spaces of one shape, warm-start seed
-rows, the `reshard` resume path, and committed placement on a device.
-The device copy of a space's geometry lives as long as its cache entry.
+each row's space encoding (zero for dummy rows) and the settings
+scalars.  Covered: both layouts, row extents 2 (one member and a dummy),
+5 and 8, a chunk mixing two spaces of one shape, warm-start seed rows,
+the `reshard` resume path, and committed placement on a device.  The
+device copy of a space's encoding lives as long as its cache entry.
 """
 
 import gc
@@ -42,10 +42,10 @@ def _record_builds(session):
         rows = max(len(members), 2)
         state, args, _ = session._chunk_arrays(
             members, shape, cap, n_init_slots, rows, resume=resume)
-        one = session._geom(members[0].job.space)
+        one = session._encoding(members[0].job.space)
         geom = np.zeros((rows,) + one.shape, one.dtype)
         for i, rec in enumerate(members):
-            geom[i] = session._geom(rec.job.space)
+            geom[i] = session._encoding(rec.job.space)
         st = session.settings
         want = list(state) + [geom] + list(args) + [
             np.asarray(st.min_observations, np.int32),
@@ -76,11 +76,11 @@ def _jobs(spaces, count):
 
 
 CASES = (
-    [(layout, m, "one space") for layout in ("feature", "fused", "gather")
+    [(layout, m, "one space") for layout in ("feature", "fused")
      for m in (1, 5, 8)]
-    + [(layout, 8, "two spaces") for layout in ("feature", "fused", "gather")]
+    + [(layout, 8, "two spaces") for layout in ("feature", "fused")]
     + [("feature", 5, "warm seeds")]
-    + [(layout, 5, "resume") for layout in ("feature", "fused", "gather")]
+    + [(layout, 5, "resume") for layout in ("feature", "fused")]
 )
 
 
@@ -181,10 +181,10 @@ def test_pack_refuses_other_widths():
 
 
 def test_device_geometry_is_evicted_with_its_space():
-    """The device copy of a space's geometry belongs to the space's cache
+    """The device copy of a space's encoding belongs to the space's cache
     entry: chunks reuse it while any search over the space is live, and
     it is dropped when the last one retires, as the host copy is."""
-    session = TuningSession(layout="gather", warm_start=False,
+    session = TuningSession(layout="feature", warm_start=False,
                             settings=BOSettings(max_iters=8))
     space, table = synth_space_table(N)
     for s in range(10):
@@ -194,7 +194,7 @@ def test_device_geometry_is_evicted_with_its_space():
     entry = session._spaces[id(space)]
     assert isinstance(entry, _SpaceEntry)
     (dev,) = entry.dev_geom.values()
-    assert dev.shape == (N, N)  # the gather layout's distance tensor
+    assert dev.shape == (N, 5)  # the space's (n, d) encoding
     alive = weakref.ref(dev)
     del dev, entry
     (g,) = session.telemetry.groups().values()

@@ -2,12 +2,12 @@
 
 The feature-buffer engine's whole correctness story is ONE claim: the
 (B,B)/(B,n) raw squared-distance blocks computed on the fly from the packed
-(B,d) feature buffer are **bit-identical** to (a) gathering the same
-entries out of the precomputed (n,n) tensor (the retained PR-2 layout) and
-(b) the readable `gp.pairwise_sqdist` on the gathered point set — and that
-finite garbage in packed slots ≥ t changes nothing.  Everything downstream
-of the blocks is shared op-for-op (`fast_bo._packed_core`), so block
-identity ⇒ pick identity ⇒ trace identity.
+(B,d) feature buffer are **bit-identical** to (a) the same entries of the
+whole-space `gp.pairwise_sqdist` tensor and (b) the readable
+`gp.pairwise_sqdist` on the gathered point set — and that finite garbage
+in packed slots ≥ t changes nothing.  Everything downstream of the blocks
+is a fixed function of them (`fast_bo.bo_step_core`), so block identity ⇒
+pick identity ⇒ trace identity.
 
 Randomized draws run twice: as Hypothesis properties when hypothesis is
 installed (`hypothesis_compat`), and as a fixed seed-parametrized lane that
@@ -25,19 +25,14 @@ from hypothesis_compat import given, settings, st
 from repro.core.fast_bo import (
     FleetState,
     bo_step_core,
-    bo_step_core_gather,
     encode_features,
     fleet_step,
-    gather_sqdist_blocks,
     packed_sqdist_blocks,
-    precompute_d2,
 )
 from repro.core.gp import pairwise_sqdist
 
 _blocks_feature = jax.jit(packed_sqdist_blocks)
-_blocks_gather = jax.jit(gather_sqdist_blocks)
 _core_feature = jax.jit(bo_step_core)
-_core_gather = jax.jit(bo_step_core_gather)
 
 
 def _draw_case(seed: int, n: int, d: int, capacity: int, t: int):
@@ -69,19 +64,18 @@ def _assert_blocks_identical(seed, n, d, capacity, t):
         seed, n, d, capacity, t
     )
     xj = jnp.asarray(x)
-    d2 = precompute_d2(x)
+    full = np.asarray(jax.jit(pairwise_sqdist)(xj))  # (n, n), whole space
 
     bb_f, bn_f = _blocks_feature(jnp.asarray(feats), xj, jnp.asarray(tried))
-    bb_g, bn_g = _blocks_gather(d2, jnp.asarray(tried))
-    bb_f, bn_f, bb_g, bn_g = map(np.asarray, (bb_f, bn_f, bb_g, bn_g))
+    bb_f, bn_f = map(np.asarray, (bb_f, bn_f))
 
-    # Valid slots: feature blocks == d²-gather blocks, bit for bit.  (The
-    # padded rows legitimately differ — gather reads row 0, feature reads
-    # the garbage features — and are masked exactly downstream.)
-    np.testing.assert_array_equal(bb_f[:t, :t], bb_g[:t, :t])
-    np.testing.assert_array_equal(bn_f[:t], bn_g[:t])
+    # Valid slots: the feature blocks are entries of the whole-space
+    # tensor, bit for bit.  (Padded rows read the garbage features and are
+    # masked exactly downstream.)
+    np.testing.assert_array_equal(bb_f[:t, :t], full[order][:, order])
+    np.testing.assert_array_equal(bn_f[:t], full[order])
 
-    # … and both match the readable gp.py reference: the cross block IS
+    # … and they match the readable gp.py reference: the cross block IS
     # `gp.pairwise_sqdist` on the observed subset, bit for bit, and the
     # training block is its column gather.  (A direct (B,B) self-call of
     # pairwise_sqdist can fuse differently at d = 1 — the very divergence
@@ -101,7 +95,6 @@ def _assert_cores_identical_and_padding_inert(seed, n, d, capacity, t):
     if t == 0:
         return  # no observations: the step is init-scripted, nothing to pin
     xj = jnp.asarray(x)
-    d2 = precompute_d2(x)
     cand = jnp.asarray(~obs)
     obs_j = jnp.asarray(obs)
     tj = jnp.asarray(t, jnp.int32)
@@ -117,14 +110,6 @@ def _assert_cores_identical_and_padding_inert(seed, n, d, capacity, t):
     assert int(got[0]) == int(ref[0])
     assert float(got[1]) == float(ref[1])  # bitwise, no tolerance
     assert float(got[2]) == float(ref[2])
-
-    # Cross-layout identity: the retained d²-gather core (with its own
-    # garbage in padded tried slots) lands on the identical bits.
-    gat = _core_gather(d2, jnp.asarray(tried_g), jnp.asarray(py_g), tj,
-                       obs_j, cand)
-    assert int(gat[0]) == int(ref[0])
-    assert float(gat[1]) == float(ref[1])
-    assert float(gat[2]) == float(ref[2])
 
 
 # Fixed shape pool — drawn cases index into it so the jitted helpers
@@ -195,11 +180,13 @@ class TestLockstepExtents:
 
 
 class TestNoQuadraticIntermediates:
-    """The acceptance-criterion guard: the traced feature-buffer lockstep
-    program at n = 32768 must not contain ANY intermediate of extent n² —
-    checked structurally on the jaxpr, so it costs a trace, not a run."""
+    """The acceptance-criterion guard: the traced lockstep program of
+    either layout at n = 32768 must not contain ANY intermediate of extent
+    n² — checked structurally on the jaxpr, so it costs a trace, not a
+    run."""
 
-    def test_fleet_step_jaxpr_has_no_n_squared(self):
+    @pytest.mark.parametrize("layout", ["feature", "fused"])
+    def test_fleet_step_jaxpr_has_no_n_squared(self, layout):
         n, b, d, j = 32768, 24, 6, 2
         state = FleetState(
             obs=jnp.zeros((j, n), bool),
@@ -222,7 +209,7 @@ class TestNoQuadraticIntermediates:
                     jnp.asarray(0.0, jnp.float32),
                     jnp.asarray(True),
                     0.0,
-                    "feature",
+                    layout,
                 )
             )(s, g, c, p, r, ip, ic, mt)
 
@@ -255,6 +242,6 @@ class TestNoQuadraticIntermediates:
         # The biggest legitimate tensor is the (j, B, n) cross block; n²
         # would be ~1400x larger.
         assert largest <= 4 * j * b * n, (
-            f"feature-buffer program materializes a {largest:,}-element "
+            f"{layout} program materializes a {largest:,}-element "
             f"intermediate at n={n} — the O(n²) wall is back"
         )

@@ -6,20 +6,26 @@ sequences between `batched_search` (J jobs advanced in device-resident
 lockstep) and J runs of the sequential engine with the same seeds — the
 contract that makes fleet mode a pure execution optimization — including
 across packed-buffer capacities (heterogeneous trial budgets group by
-(shape, B)), space extents (n = 69 exhaustion = full buffer, synthetic
-n = 512 and n = 8192 in the budgeted B ≪ n regime), and packed geometry
-layouts (the default feature buffer vs the retained d²-gather path,
-`layout="gather"` — both must land on identical bits).  The fast tests
+(shape, B)) and space extents (n = 69 exhaustion = full buffer,
+synthetic n = 512 and n = 8192 in the budgeted B ≪ n regime).  The fast tests
 mostly share array shapes so the engine compiles few programs; the
 exhaustive 69-config cluster sweep and the n = 8192 identity are marked
 `slow`.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from golden import assert_traces_match
 from repro.core.bayesopt import BOSettings, cherrypick_search, ruya_search
+from repro.core.fast_bo import (
+    FleetState,
+    SequentialProbe,
+    encode_features,
+    fleet_step,
+)
 from repro.core.memory_model import fit_memory_model
 from repro.core.search_space import Configuration, SearchSpace
 from repro.fleet import (
@@ -28,8 +34,10 @@ from repro.fleet import (
     batched_search,
     cluster_fleet,
     replay_seeds,
+    TuningSession,
     tune_fleet,
 )
+from repro.fleet.batched_engine import _fleet_update
 
 GiB = 1024**3
 N = 20
@@ -269,40 +277,29 @@ class TestTraceEquivalenceScaling:
             space, [table] * 2, [np.random.default_rng(s) for s in range(2)],
             to_exhaustion=True,
         )
-        # The retained d²-gather layout must land on the identical traces —
-        # batched↔feature↔gather↔golden, all bit-for-bit.
-        bt_g = batched_search(
-            space, [table] * 2, [np.random.default_rng(s) for s in range(2)],
-            to_exhaustion=True, layout="gather",
-        )
-        for b in (bt, bt_g):
-            assert all(len(b.job_trace(j).tried) == 69 for j in range(2))
-            assert_traces_match("n69-exhaustion", b.traces(), jobs=[0, 1])
+        assert all(len(bt.job_trace(j).tried) == 69 for j in range(2))
+        assert_traces_match("n69-exhaustion", bt.traces(), jobs=[0, 1])
 
     def test_n512_budgeted_matches_golden(self):
         space, table = synth_space_table(512)
         st = BOSettings(max_iters=10)
         prio = list(range(0, 50))
         rest = list(range(50, 512))
-        for layout in ("feature", "gather"):
-            bt = batched_search(
-                space, [table] * 3,
-                [np.random.default_rng(s) for s in range(3)],
-                priority=[prio] * 3, remaining=[rest] * 3, settings=st,
-                to_exhaustion=True, layout=layout,
-            )
-            assert all(len(bt.job_trace(j).tried) == 10 for j in range(3))
-            assert_traces_match("n512-budgeted", bt.traces(), jobs=[0, 1, 2])
+        bt = batched_search(
+            space, [table] * 3,
+            [np.random.default_rng(s) for s in range(3)],
+            priority=[prio] * 3, remaining=[rest] * 3, settings=st,
+            to_exhaustion=True,
+        )
+        assert all(len(bt.job_trace(j).tried) == 10 for j in range(3))
+        assert_traces_match("n512-budgeted", bt.traces(), jobs=[0, 1, 2])
 
 
 @pytest.mark.slow
 class TestTraceEquivalenceLargeSpace:
     """The 10⁴-regime identity (slow lane): a budgeted search over n = 8192
-    must produce bit-identical traces from the sequential feature-buffer
-    engine, the batched feature-buffer engine, and the retained d²-gather
-    engine (which at this extent holds a 268 MB (n,n) tensor — the memory
-    wall the feature buffer removes; this is the largest space the gather
-    cross-check runs on)."""
+    must produce bit-identical traces from the sequential and the batched
+    feature-buffer engines."""
 
     def test_n8192_budgeted_identical(self):
         space, table = synth_space_table(8192)
@@ -320,15 +317,9 @@ class TestTraceEquivalenceLargeSpace:
             priority=[prio] * 2, remaining=[rest] * 2, settings=st,
             to_exhaustion=True,
         )
-        bt_g = batched_search(
-            space, [table] * 2, [np.random.default_rng(s) for s in range(2)],
-            priority=[prio] * 2, remaining=[rest] * 2, settings=st,
-            to_exhaustion=True, layout="gather",
-        )
         for j, ref in enumerate(refs):
             assert len(ref.tried) == 12
             assert_traces_equal(bt.job_trace(j), ref)
-            assert_traces_equal(bt_g.job_trace(j), ref)
 
 
 @pytest.mark.slow
@@ -407,6 +398,62 @@ class TestFleetDriver:
         job = FleetJob(name="quad", space=quad_space(), cost_table=quad_table())
         with pytest.raises(ValueError):
             tune_fleet([job, job], [np.random.default_rng(0)])
+
+
+_LAYOUT_ENTRY_POINTS = {
+    "TuningSession": lambda layout: TuningSession(layout=layout),
+    "fleet_step": lambda layout: fleet_step(*[None] * 11, layout=layout),
+    "SequentialProbe": lambda layout: SequentialProbe(
+        np.zeros((4, 2), np.float32), 2, layout=layout),
+    "batched_search": lambda layout: batched_search(
+        quad_space(), [quad_table()], [np.random.default_rng(0)],
+        layout=layout),
+}
+
+
+@pytest.mark.parametrize("layout", ["feature", "fused"])
+def test_lockstep_update_donates_its_state(layout):
+    """The chunk update donates its state: after one call the input
+    state's buffers are deleted (XLA aliased them to the outputs), so a
+    chunk's steps update the mask and the packed trial, target and
+    feature buffers in place instead of copying them."""
+    n, rows, b = 16, 2, 6
+    space, table = synth_space_table(n)
+    enc = encode_features(space.encoded())
+    d = enc.shape[1]
+    state = FleetState(
+        obs=jnp.zeros((rows, n), bool),
+        tried=jnp.full((rows, b), -1, jnp.int32),
+        py=jnp.zeros((rows, b), jnp.float32),
+        feats=jnp.zeros((rows, b, d), jnp.float32),
+        t=jnp.zeros(rows, jnp.int32),
+        stop=jnp.full(rows, -1, jnp.int32),
+        pb=jnp.full(rows, -1, jnp.int32),
+        done=jnp.zeros(rows, bool),
+        last_ei=jnp.zeros(rows, jnp.float32),
+        last_best=jnp.full(rows, jnp.inf, jnp.float32),
+    )
+    old = (state.obs, state.tried, state.py, state.feats)
+    new = _fleet_update(
+        state, jnp.asarray(np.stack([enc] * rows)),
+        jnp.asarray(np.stack([table] * rows), jnp.float32),
+        jnp.ones((rows, n), bool), jnp.zeros((rows, n), bool),
+        jnp.zeros((rows, 1), jnp.int32), jnp.zeros(rows, jnp.int32),
+        jnp.full(rows, b, jnp.int32), jnp.asarray(0, jnp.int32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(True),
+        xi=0.0, layout=layout,
+    )
+    jax.block_until_ready(new.t)
+    assert [buf.is_deleted() for buf in old] == [True] * 4
+    assert int(new.t[0]) == 1  # the step ran: one trial observed per row
+
+
+@pytest.mark.parametrize("entry", sorted(_LAYOUT_ENTRY_POINTS))
+def test_unknown_layout_is_refused(entry):
+    """Every entry point that takes a layout knows exactly two, and
+    names them when refusing another."""
+    with pytest.raises(ValueError, match=r"\('feature', 'fused'\)"):
+        _LAYOUT_ENTRY_POINTS[entry]("gather")
 
 
 def linear_run_fn(slope_gb, base_gb=0.5, rate_s_per_gb=50.0):

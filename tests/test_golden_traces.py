@@ -4,11 +4,11 @@ variant must reproduce the committed fixtures bit-for-bit.
 This is the acceptance harness of the sharded fleet engine: the three
 pinned scenarios (n = 69 exhaustion, n = 512 budgeted two-phase, and a
 streaming warm-start session — `tests/golden/scenarios.py`) are replayed
-through the unsharded reference AND across shard counts 2/4, on all three
-packed-geometry layouts — "feature", the retained d²-"gather", and the
-"fused" streaming-kernel lane (`repro.kernels.ei_argmax`) — and compared
-to `tests/golden/*.json` with the shared `assert_outcomes_match` helper.  The sequential per-job engine is
-pinned against the same fixtures, which closes the loop:
+through the unsharded reference AND across shard counts 2/4, on both
+layouts — "feature" and the "fused" streaming-kernel lane
+(`repro.kernels.ei_argmax`) — and compared to `tests/golden/*.json` with
+the shared `assert_outcomes_match` helper.  The sequential per-job engine
+is pinned against the same fixtures, which closes the loop:
 
     sequential == golden == session(layout × shard count)
 
@@ -51,7 +51,7 @@ def _need_devices(shard):
 
 
 @pytest.mark.parametrize("shard", SHARD_COUNTS)
-@pytest.mark.parametrize("layout", ("feature", "gather", "fused"))
+@pytest.mark.parametrize("layout", ("feature", "fused"))
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
 def test_scenario_matches_golden(scenario, layout, shard):
     _need_devices(shard)
@@ -74,14 +74,6 @@ class TestSequentialReference:
             for s in range(2)
         ]
         assert_traces_match("n69-exhaustion", traces, jobs=[0, 1])
-
-    def test_n69_exhaustion_sequential_gather_layout(self):
-        space, table = synth_space_table(69)
-        trace = cherrypick_search(
-            space, lambda i: float(table[i]), np.random.default_rng(0),
-            to_exhaustion=True, layout="gather",
-        )
-        assert_traces_match("n69-exhaustion", [trace], jobs=[0])
 
     def test_n512_budgeted_sequential(self):
         space, table = synth_space_table(512)

@@ -116,7 +116,7 @@ class TestStaticDrainIdentity:
         A 2-job prefix of the pinned fleet, submitted through handles —
         lockstep extent 2 here vs 4 in the fixture run, so this also
         re-pins the batch-extent invariance the chunking rests on.  (The
-        gather-layout and sharded variants ride `tests/test_golden_traces`;
+        fused-layout and sharded variants ride `tests/test_golden_traces`;
         `batched_search` is now a session shim.)"""
         space, table = synth_space_table(69)
         session = TuningSession(mode="cherrypick", to_exhaustion=True)
@@ -139,7 +139,7 @@ class TestStaticDrainIdentity:
         st_ = BOSettings(max_iters=10)
         prio = list(range(0, 50))
         rest = list(range(50, 512))
-        for layout in ("feature", "gather"):
+        for layout in ("feature", "fused"):
             session = TuningSession(settings=st_, to_exhaustion=True,
                                     layout=layout)
             handles = [
@@ -256,12 +256,12 @@ class TestStaticDrainIdentity:
             assert_trace_equal(b.trace, s.trace)
 
     def test_session_releases_per_job_state_at_retirement(self):
-        """Finished jobs must not pin cost tables / encodings / geometry:
-        the refcounted per-space and per-job cache entries are evicted when
+        """Finished jobs must not pin cost tables or encodings: the
+        refcounted per-space and per-job cache entries are evicted when
         their last active submission retires."""
         session = TuningSession(mode="cherrypick", to_exhaustion=True,
                                 settings=BOSettings(max_iters=4),
-                                layout="gather")
+                                layout="feature")
         for s in range(2):
             session.submit(
                 FleetJob(name=f"j{s}", space=quad_space(),
