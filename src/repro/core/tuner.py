@@ -41,9 +41,17 @@ __all__ = ["RuyaReport", "run_ruya", "run_cherrypick"]
 
 @dataclasses.dataclass
 class RuyaReport:
+    """One job's profile, §III-D split and search trace.
+
+    ``priority`` / ``remaining`` are the split's pools, in pool order:
+    tuples from `run_ruya`, and from a session's `SearchOutcome.report()`
+    the outcome's `Indices`, an immutable integer sequence backed by a
+    read-only array that compares equal to the tuple and converts with
+    ``np.asarray`` at no copy."""
+
     profile: ProfileResult
-    priority: Tuple[int, ...]
-    remaining: Tuple[int, ...]
+    priority: Sequence[int]
+    remaining: Sequence[int]
     trace: SearchTrace
 
     @property

@@ -61,8 +61,11 @@ thin deprecation shims over this engine.
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 import hashlib
+import numbers
+import operator
 import time
 import weakref
 from typing import (
@@ -107,6 +110,7 @@ if TYPE_CHECKING:  # import cycle: driver imports session for tune_fleet
 
 __all__ = [
     "FleetFailedError",
+    "Indices",
     "JobHandle",
     "SearchOutcome",
     "TrialRecord",
@@ -211,6 +215,80 @@ class FleetFailedError(RuntimeError):
     outcomes stay available via `results()`)."""
 
 
+class Indices(collections.abc.Sequence):
+    """An immutable sequence of configuration indices, backed by a
+    read-only 1-D int64 array.
+
+    It stands where a tuple of ints stood: indexing gives a Python `int`
+    (a slice gives an `Indices`), iteration yields Python ints, ``in`` is
+    one vectorized comparison, the hash is the tuple's, and ``==``
+    against another `Indices`, a tuple, a list or an ndarray is a plain
+    `bool` on the elements, so it compares equal to the tuple it
+    replaces.  ``np.asarray(indices)`` returns the backing array without
+    a copy; the array is read-only, so a write through it raises.
+
+    The constructor copies ``values``; the session wraps the arrays its
+    split made at submit without a copy (`_wrap`), so publishing an
+    outcome boxes nothing."""
+
+    __slots__ = ("_a",)
+
+    def __init__(self, values: Sequence[int] = ()) -> None:
+        a = np.array(values, dtype=np.int64).reshape(-1)
+        a.flags.writeable = False
+        self._a = a
+
+    @classmethod
+    def _wrap(cls, a: np.ndarray) -> "Indices":
+        """View of a read-only 1-D int64 array that nothing writes."""
+        if a.dtype != np.int64 or a.ndim != 1 or a.flags.writeable:
+            raise ValueError("Indices wraps read-only 1-D int64 arrays only")
+        out = cls.__new__(cls)
+        out._a = a
+        return out
+
+    def __len__(self) -> int:
+        return self._a.shape[0]
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return Indices._wrap(self._a[i])
+        return int(self._a[operator.index(i)])
+
+    def __iter__(self):
+        return iter(self._a.tolist())
+
+    def __contains__(self, value) -> bool:
+        if isinstance(value, (numbers.Number, np.number)):
+            return bool((self._a == value).any())
+        return super().__contains__(value)
+
+    def __eq__(self, other) -> bool:
+        if isinstance(other, Indices):
+            other = other._a
+        elif not isinstance(other, (tuple, list, np.ndarray)):
+            return NotImplemented
+        if len(other) != len(self):
+            return False
+        return bool(np.array_equal(self._a, np.asarray(other)))
+
+    def __hash__(self) -> int:
+        return hash(tuple(self))
+
+    def __repr__(self) -> str:
+        return f"Indices({self._a.tolist()!r})"
+
+    def __reduce__(self):
+        return (Indices, (self._a,))
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        if dtype is None or np.dtype(dtype) == self._a.dtype:
+            return self._a.copy() if copy else self._a
+        if copy is False:
+            raise ValueError(f"reading Indices as {dtype} needs a copy")
+        return self._a.astype(dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class TrialRecord:
     """One observation: which config, what it cost, when, and why.
@@ -293,6 +371,11 @@ class SearchOutcome:
     to `pareto()`, `best_usd` and `best_runtime_h`.  Both serialize only
     when non-default, so unpriced runtime-objective outcomes (every
     committed golden fixture) keep their exact legacy `as_dict` form.
+
+    ``priority`` / ``remaining`` are the §III-D split's pools, in pool
+    order.  The session publishes them as `Indices`: an immutable integer
+    sequence backed by a read-only array, equal to the tuple it replaces;
+    ``np.asarray(outcome.priority)`` costs no copy.
     """
 
     name: str
@@ -300,8 +383,8 @@ class SearchOutcome:
     seeded: List[TrialRecord]
     stop_iteration: Optional[int]
     phase_boundary: Optional[int]
-    priority: Tuple[int, ...]
-    remaining: Tuple[int, ...]
+    priority: Sequence[int]
+    remaining: Sequence[int]
     profile: Optional[ProfileResult] = None
     signature: Optional[MemorySignature] = None
     status: str = "converged"
@@ -434,8 +517,8 @@ class SearchOutcome:
             "seeded": [r.as_dict() for r in self.seeded],
             "stop_iteration": self.stop_iteration,
             "phase_boundary": self.phase_boundary,
-            "priority": [int(i) for i in self.priority],
-            "remaining": [int(i) for i in self.remaining],
+            "priority": np.asarray(self.priority, np.int64).tolist(),
+            "remaining": np.asarray(self.remaining, np.int64).tolist(),
             "status": str(self.status),
             "profile_attempts": int(self.profile_attempts),
             "retry_backoff_s": float(self.retry_backoff_s),
@@ -465,8 +548,8 @@ class SearchOutcome:
             seeded=[TrialRecord.from_dict(r) for r in d["seeded"]],
             stop_iteration=None if stop is None else int(stop),
             phase_boundary=None if pb is None else int(pb),
-            priority=tuple(int(i) for i in d["priority"]),
-            remaining=tuple(int(i) for i in d["remaining"]),
+            priority=Indices(d["priority"]),
+            remaining=Indices(d["remaining"]),
             status=status,
             profile_attempts=int(d.get("profile_attempts", 1)),
             retry_backoff_s=float(d.get("retry_backoff_s", 0.0)),
@@ -544,8 +627,8 @@ class _JobRec:
     profile: Optional[ProfileResult]
     signature: Optional[MemorySignature]
     class_key: Optional[Tuple[MemorySignature, int, int]]
-    prio_idx: np.ndarray  # (p,) int64, pool order
-    rem_idx: np.ndarray  # (r,) int64, pool order
+    prio_idx: np.ndarray  # (p,) int64, pool order, read-only
+    rem_idx: np.ndarray  # (r,) int64, pool order, read-only
     profile_attempts: int = 1  # profiling attempts incl. retries
     retry_backoff_s: float = 0.0  # charged profiling backoff
     status: str = "converged"  # terminal status, set before publication
@@ -875,10 +958,12 @@ class TuningSession:
         profile: Optional[ProfileResult] = None
         signature: Optional[MemorySignature] = None
         if priority is not None:
-            prio_idx = np.asarray(priority, np.int64).reshape(-1)
+            # Copies: the outcome publishes these arrays, and a later write
+            # by the caller to what it passed must not reach it.
+            prio_idx = np.array(priority, np.int64).reshape(-1)
             rem_idx = (
                 np.zeros(0, np.int64) if remaining is None
-                else np.asarray(remaining, np.int64).reshape(-1)
+                else np.array(remaining, np.int64).reshape(-1)
             )
             if len(np.intersect1d(prio_idx, rem_idx)):
                 raise ValueError(
@@ -928,6 +1013,9 @@ class TuningSession:
                 rem_mask = ~prio_mask
                 prio_idx = np.flatnonzero(prio_mask)
                 rem_idx = np.flatnonzero(rem_mask)
+        # Published as the outcome's `Indices` at retirement, uncopied.
+        prio_idx.flags.writeable = False
+        rem_idx.flags.writeable = False
 
         budget = trial_budget(len(prio_idx), len(rem_idx), self.settings)
 
@@ -1110,7 +1198,7 @@ class TuningSession:
         Three spans, each with its counter: ``tuning.dispatch`` (the
         enqueue of the update), ``tuning.poll`` (the done-flag sync) and
         ``tuning.retire`` (the pre-retirement sync, `_retire` and the
-        publishes).  While a trace records, ``tuning.dispatch`` carries
+        publishes, each in a ``tuning.publish``).  While a trace records, ``tuning.dispatch`` carries
         the EI tail's ``rows`` and ``slots`` for this update (`ei_work`);
         the poll keeps the flags it read for them."""
         counters = self.telemetry.group(ch.group_key)
@@ -1268,10 +1356,11 @@ class TuningSession:
                 rec.status = status
                 # Never admitted: no engine row to read — the outcome is
                 # just the warm seeds (if any) and zero executed trials.
-                self._publish(
-                    rec, k=len(rec.seed_trials), tried_row=None,
-                    stop=-1, pb=-1, failure=reason,
-                )
+                with span("tuning.publish"):
+                    self._publish(
+                        rec, k=len(rec.seed_trials), tried_row=None,
+                        stop=-1, pb=-1, failure=reason,
+                    )
                 return True
         for ch in self._chunks:
             for i, rec in enumerate(ch.members):
@@ -1292,14 +1381,15 @@ class TuningSession:
         ``live = ~done & budget_left``, so a done row is inert — its
         chunk-mates' traces are untouched)."""
         rows = collapse_rows(ch.state, ch.n_shards)
-        self._publish(
-            rec,
-            k=int(rows.t[i]),
-            tried_row=rows.tried[i],
-            stop=int(rows.stop[i]),
-            pb=int(rows.pb[i]),
-            failure=reason,
-        )
+        with span("tuning.publish"):
+            self._publish(
+                rec,
+                k=int(rows.t[i]),
+                tried_row=rows.tried[i],
+                stop=int(rows.stop[i]),
+                pb=int(rows.pb[i]),
+                failure=reason,
+            )
         ch.members[i] = None
         done = np.array(ch.state.done)  # writable host copy
         done.reshape(-1)[i] = True
@@ -1444,8 +1534,8 @@ class TuningSession:
             seeded=[],
             stop_iteration=None,
             phase_boundary=None,
-            priority=(),
-            remaining=(),
+            priority=Indices(),
+            remaining=Indices(),
             status="failed",
             failure=f"{type(error).__name__}: {error}",
             profile_attempts=je[3] if je is not None else 1,
@@ -1788,10 +1878,11 @@ class TuningSession:
         for i, rec in enumerate(ch.members):
             if rec is None:
                 continue  # retired mid-flight; outcome already published
-            self._publish(
-                rec, k=int(s_t[i]), tried_row=s_tried[i],
-                stop=int(s_stop[i]), pb=int(s_pb[i]),
-            )
+            with span("tuning.publish"):
+                self._publish(
+                    rec, k=int(s_t[i]), tried_row=s_tried[i],
+                    stop=int(s_stop[i]), pb=int(s_pb[i]),
+                )
 
     def _publish(
         self, rec: _JobRec, k: int, tried_row, stop: int, pb: int,
@@ -1834,9 +1925,9 @@ class TuningSession:
             seeded=list(rec.seed_trials),
             stop_iteration=stop if stop >= 0 else None,
             phase_boundary=pb if pb >= 0 else None,
-            # tolist() boxes at C speed; built once, at retirement.
-            priority=tuple(rec.prio_idx.tolist()),
-            remaining=tuple(rec.rem_idx.tolist()),
+            # The split's own read-only arrays: nothing copied or boxed.
+            priority=Indices._wrap(rec.prio_idx),
+            remaining=Indices._wrap(rec.rem_idx),
             profile=rec.profile,
             signature=rec.signature,
             status=rec.status,

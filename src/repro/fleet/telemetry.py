@@ -18,6 +18,8 @@ arguments are attached only while a trace records.
                                                       (rows, slots: `ei_work`)
     tuning.poll          TuningSession._step_chunk    the done-flag sync
     tuning.retire        TuningSession._step_chunk    sync, retire, publish
+    tuning.publish       TuningSession._publish's     one search's outcome,
+                         callers                      history, listeners
     tuning.lock_wait     the session lock             a contended acquire
     tuning.idle          TuningService._idle_wait     a worker with no work
 

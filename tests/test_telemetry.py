@@ -8,6 +8,7 @@ with its span.
 """
 
 import glob
+import importlib.util
 import json
 import os
 import sys
@@ -26,6 +27,8 @@ from repro.fleet.telemetry import Telemetry, TimedLock
 from golden.scenarios import synth_space_table
 
 pytestmark = pytest.mark.service
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _session_kwargs():
@@ -245,6 +248,90 @@ class TestAdmissionTransfers:
         assert all("tuning.admit" in s["parents"] for s in spans)
         assert sum(s["args"]["puts"] for s in spans) == g["admit_puts"]
         assert sum(s["args"]["bytes"] for s in spans) == g["admit_bytes"]
+
+
+class TestPublishSpans:
+    @pytest.mark.parametrize("cancel", [False, True])
+    def test_one_publish_span_per_published_search(self, tmp_path, cancel):
+        """Every publication opens one ``tuning.publish`` span: those of a
+        chunk's retirement under ``tuning.retire``, a mid-flight cancel's
+        outside it."""
+        session = TuningSession(**_session_kwargs())
+        space, table = synth_space_table(24)
+        with jax.profiler.trace(str(tmp_path)):
+            handles = [
+                session.submit(FleetJob(name=f"j{s}", space=space,
+                                        cost_table=table),
+                               seed=s, mode="cherrypick")
+                for s in range(5)
+            ]
+            if cancel:
+                session._admit()
+                session.step()
+                assert session.cancel(handles[0])
+            outs = session.drain()
+        spans = _named(_spans(str(tmp_path)), "tuning.publish")
+        assert len(outs) == 5
+        assert len(spans) == len(outs)
+        retired = [s for s in spans if "tuning.retire" in s["parents"]]
+        assert len(retired) == 5 - int(cancel)
+        assert [o.status for o in outs].count("cancelled") == int(cancel)
+
+
+def _bench_module(monkeypatch, *parts):
+    """A module of the benchmark (``bench/``), loaded as the harness
+    loads it, with ``bench/`` importable for its own imports."""
+    bench = os.path.join(ROOT, "bench")
+    monkeypatch.syspath_prepend(bench)
+    path = os.path.join(bench, *parts)
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + "_".join(parts), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class TestPublishReader:
+    """``bench/metrics/publish_ms_per_search.catalog.py`` on a hand-made
+    span table: the mean ``tuning.publish`` span in the window, in ms."""
+
+    @staticmethod
+    def _trace(names):
+        a, b = ("/host:CPU", 1), ("/host:CPU", 2)
+        spans = {
+            "tuning.publish": [
+                (-2_000_000, 1_000_000, a),  # starts before the window
+                (10_000_000, 13_000_000, a),  # under a retirement
+                (14_000_000, 15_000_000, a),
+                (30_000_000, 32_000_000, b),  # a cancel's, elsewhere
+            ],
+            "tuning.retire": [(9_000_000, 20_000_000, a)],
+        }
+        return {
+            "devices": {},
+            "spans": sorted((s, e, name, tid) for name in names
+                            for s, e, tid in spans[name]),
+            "window": (0, 100_000_000),
+        }
+
+    def test_mean_publish_span_in_ms(self, monkeypatch):
+        reader = _bench_module(monkeypatch, "metrics",
+                               "publish_ms_per_search.catalog.py")
+        trace = self._trace(["tuning.publish", "tuning.retire"])
+        monkeypatch.setattr(reader.program_trace, "for_run",
+                            lambda ctx: reader.program_trace.reduce(trace))
+        assert reader.read({}) == pytest.approx((3.0 + 1.0 + 2.0) / 3)
+
+    def test_nothing_to_read_gives_none(self, monkeypatch):
+        reader = _bench_module(monkeypatch, "metrics",
+                               "publish_ms_per_search.catalog.py")
+        pt = reader.program_trace
+        # A program without the span, as before publication was traced.
+        trace = self._trace(["tuning.retire"])
+        monkeypatch.setattr(pt, "for_run", lambda ctx: pt.reduce(trace))
+        assert reader.read({}) is None
+        monkeypatch.setattr(pt, "for_run", lambda ctx: None)
+        assert reader.read({}) is None
 
 
 class TestTimedLock:
